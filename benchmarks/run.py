@@ -53,6 +53,7 @@ from repro.core import customize, energy, scheduler           # noqa: E402
 from repro.core.machine import MachineConfig                  # noqa: E402
 from repro.core.programs import ALL, reduction                # noqa: E402
 from repro import runtime as rt                               # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache   # noqa: E402
 
 N = int(os.environ.get("BENCH_N", "64"))
 RNG = np.random.default_rng(0)
@@ -962,6 +963,7 @@ def main() -> None:
                          "(pair with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=8)")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.sharded:
         bench_runtime_sharded()

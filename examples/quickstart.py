@@ -63,7 +63,7 @@ def lm_demo():
     mesh = M.make_debug_mesh(1)
     opt_cfg = OptConfig(lr=1e-3)
     _, jit_for, _ = build_train_step(spec, mesh, opt_cfg)
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(0), spec)
         opt = opt_init(params, opt_cfg)
     data = SyntheticLM(DataConfig(vocab=spec.cfg.vocab, seq_len=64,

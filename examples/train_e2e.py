@@ -49,10 +49,11 @@ def main():
 
     mesh = M.make_debug_mesh(len(jax.devices()))
     opt_cfg = OptConfig(lr=6e-4, warmup=50)
-    _, jit_for, _ = build_train_step(spec, mesh, opt_cfg)
-    with M.use_mesh(mesh):
+    _, jit_for, (psh, osh) = build_train_step(spec, mesh, opt_cfg)
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(0), spec)
         opt = opt_init(params, opt_cfg)
+        params, opt = jax.device_put((params, opt), (psh, osh))
 
     data = SyntheticLM(DataConfig(vocab=spec.cfg.vocab, seq_len=args.seq,
                                   global_batch=args.batch))

@@ -39,7 +39,7 @@ from .state import (EXECUTE_BACKENDS, FINISHED, READY, WAIT, Counters,
                     init_state)
 from .fetch_decode import Decoded, fetch_decode
 from .read import Operands, read_operands
-from .execute import EXECUTE_STAGE_BACKENDS, execute
+from .execute import EXECUTE_STAGE_BACKENDS, execute, interpret_mode
 from .write import write_back
 from .control import control
 from .fused import fused_sm_step
@@ -90,8 +90,12 @@ def run_block_body(cfg: MachineConfig, n_warps: int, code, block_dim,
         return jnp.any(st.wstate != FINISHED) & \
             (st.counters.cycles < cfg.max_cycles)
 
-    step = {"reference": issue_one_warp,
-            "pallas_fused": fused_sm_step}.get(cfg.execute_backend, sm_step)
+    if cfg.execute_backend == "reference":
+        step = issue_one_warp
+    elif cfg.execute_backend == "pallas_fused":
+        step = functools.partial(fused_sm_step, interpret=interpret_mode())
+    else:
+        step = sm_step
     body = functools.partial(step, cfg, code, lut, block_dim_xy,
                              block_xy, grid_xy)
     st = jax.lax.while_loop(cond, body, st0)

@@ -10,7 +10,9 @@ backends implement the contract:
   the multiplier really deletes the multiply from the compiled code).
 * ``"pallas"`` — the :func:`repro.kernels.simt_alu.simt_alu` VPU kernel:
   the same datapath as a Pallas TPU kernel over (warps, lanes) tiles in
-  VMEM, run in interpret mode on CPU (``cfg.pallas_interpret``).
+  VMEM.  Interpret mode is not configured: :func:`interpret_mode`
+  derives it from the platform (the CPU only), so on the TPU the kernel
+  always lowers through Mosaic and a compiler refusal propagates.
 
 Memory loads are *not* part of the backend contract — LDG/LDS data is
 gathered by the Read stage (it needs the memory state) and merged here
@@ -20,12 +22,24 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from .. import isa
 from .state import MachineConfig
 from .fetch_decode import Decoded
 from .read import Operands
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU only.
+
+    The one platform decision of the pipeline, read at trace time by the
+    execute-stage dispatch (``"pallas"``) and the step dispatch
+    (``"pallas_fused"``).  Anywhere else the kernel is compiled, and a
+    refusal from the compiler is raised; nothing falls back to the
+    interpreter or to the jnp datapath."""
+    return jax.default_backend() == "cpu"
 
 
 def _execute_jnp(cfg: MachineConfig, dec: Decoded,
@@ -51,7 +65,7 @@ def _execute_pallas(cfg: MachineConfig, dec: Decoded,
         ops.exec_mask.astype(jnp.int32),
         enable_mul=cfg.enable_mul,
         num_read_operands=cfg.num_read_operands,
-        interpret=cfg.pallas_interpret)
+        interpret=interpret_mode())
 
 
 #: backend name -> (cfg, Decoded, Operands) -> (result, isetp nibble)

@@ -27,11 +27,14 @@ int32 (``!= 0`` / ``astype`` on either side) and the uint32
 ``stack_mask`` crosses via ``lax.bitcast_convert_type`` — both are
 bit-lossless.
 
-On CPU CI the kernel runs in interpret mode (``cfg.pallas_interpret``),
-which traces the body to the same XLA ops as the staged path — the CPU
-fallback the differential suites exercise.  On a real TPU, set
-``pallas_interpret=False``; the gathers/scatters inside the body are the
-compile-limiting construct, same as for ``simt_alu``.
+On the CPU the kernel runs in interpret mode, which traces the body to
+the same XLA ops as the staged path — what the differential suites
+exercise.  The step dispatch passes ``interpret`` from
+:func:`repro.core.pipeline.execute.interpret_mode`, so on the TPU the
+kernel lowers through Mosaic.  Mosaic refuses it today: the fetch
+gather ``code[st.pc]`` over a whole-array ref does not lower
+(``ValueError: Shape mismatch in input, indices and output``), and that
+error reaches whoever selected ``pallas_fused``.
 """
 from __future__ import annotations
 
@@ -115,7 +118,8 @@ def _fused_step_kernel(code_ref, lut_ref, geom_ref, pc_ref, wstate_ref,
 
 def fused_sm_step(cfg: MachineConfig, code: jnp.ndarray, lut: jnp.ndarray,
                   block_dim_xy: jnp.ndarray, block_xy: jnp.ndarray,
-                  grid_xy: jnp.ndarray, st: SMState) -> SMState:
+                  grid_xy: jnp.ndarray, st: SMState, *,
+                  interpret: bool) -> SMState:
     """Drop-in for :func:`sm_step` running the step as one Pallas kernel."""
     bitcast = jax.lax.bitcast_convert_type
     i32 = jnp.int32
@@ -136,7 +140,7 @@ def fused_sm_step(cfg: MachineConfig, code: jnp.ndarray, lut: jnp.ndarray,
             s(S1), s(G1), s(G1),                # smem, gmem, gw
             s(2, isa.NUM_OPCODES), s(4),        # counter vectors/scalars
         ],
-        interpret=cfg.pallas_interpret,
+        interpret=interpret,
     )(code, lut.astype(i32),
       jnp.stack([block_dim_xy, block_xy, grid_xy]),
       st.pc, st.wstate, st.sp,

@@ -46,8 +46,6 @@ class MachineConfig:
     mem_latency_shared: int = 2   # extra cycles per shared access
     max_cycles: int = 4_000_000   # runaway-program guard
     execute_backend: str = "jnp"  # see EXECUTE_BACKENDS
-    pallas_interpret: bool = True  # run the Pallas kernel in interpret mode
-    #                                (CPU); set False on real TPU hardware
 
     def __post_init__(self):
         if self.execute_backend not in EXECUTE_BACKENDS:

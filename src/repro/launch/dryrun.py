@@ -151,7 +151,7 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool,
         big = spec.cfg.param_count() > 2e10
         opt_mode = "adamw_lite" if big else "adamw"
 
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_cfg = OptConfig(mode=opt_mode)
             _, jit_for, (psh, osh) = build_train_step(

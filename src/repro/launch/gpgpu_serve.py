@@ -44,6 +44,7 @@ from repro import obs
 from repro import runtime as rt
 from repro.core import asm, isa, scheduler
 from repro.core.programs import ALL, compiled_kernels
+from repro.launch.compile_cache import enable_compile_cache
 
 #: per-kernel tenant input sizes (reduction stays single-pass; the
 #: DSL-compiled kernels ride along with their own geometries and
@@ -532,6 +533,7 @@ def main(argv=None):
                     help="make every other loadgen tenant ON-OFF "
                          "(bursts at 4x rate for a quarter duty cycle)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.skewed and args.longtail:
         ap.error("--skewed and --longtail are mutually exclusive")

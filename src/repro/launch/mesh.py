@@ -19,52 +19,40 @@ SM-level original; this module is the same policy at fleet scale.
 """
 from __future__ import annotations
 
-import contextlib
 import re
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
-def use_mesh(mesh: Mesh):
-    """Version-portable ``with use_mesh(mesh):`` context.
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """The one mesh constructor: every axis is ``AxisType.Auto``.
 
-    ``jax.set_mesh`` was removed/renamed across JAX releases
-    (``jax.sharding.use_mesh`` in newer ones); on versions predating
-    both, a ``Mesh`` is itself a context manager that installs the
-    resource environment.  All call sites go through this one shim.
+    JAX's own ``make_mesh`` defaults to Explicit axes, under which a
+    gather from a sharded array outside ``shard_map`` and a
+    ``with_sharding_constraint`` on an unsharded intermediate are type
+    errors; the runtime and the model code rely on the compiler placing
+    those.  Every mesh in the repo — the factories below and the
+    tests' — goes through here so they all state the same types.
+    ``devices`` defaults to the local ones; a compile check passes the
+    devices of a described topology.
     """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
-
-
-def _make_mesh(shape, axes) -> Mesh:
-    """Version-portable mesh construction, same spirit as ``use_mesh``:
-    ``jax.make_mesh`` does not exist on older releases, where the
-    equivalent is a ``Mesh`` over ``mesh_utils.create_device_mesh``.
-    Every mesh factory below goes through this one shim."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    return Mesh(mesh_utils.create_device_mesh(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int = 1) -> Mesh:
     """Tiny mesh over real local devices for tests."""
-    return _make_mesh((1, n_devices), ("data", "model"))
+    return make_mesh((1, n_devices), ("data", "model"))
 
 
 def make_sm_mesh(n_sm: int) -> Mesh:
@@ -76,7 +64,7 @@ def make_sm_mesh(n_sm: int) -> Mesh:
     placement, which is still the same policy).
     """
     n = min(max(1, n_sm), len(jax.devices()))
-    return _make_mesh((n,), ("sm",))
+    return make_mesh((n,), ("sm",))
 
 
 def batch_axes(mesh: Mesh):

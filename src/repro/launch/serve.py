@@ -38,7 +38,7 @@ def main(argv=None):
     mesh = M.make_debug_mesh(len(jax.devices()))
     max_seq = args.prompt_len + args.gen
 
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(args.seed), spec)
         state = api.decode_state(spec, args.batch, max_seq)
         _, jit_for, _ = build_serve_step(spec, mesh, donate=True)

@@ -58,9 +58,10 @@ def main(argv=None):
     _, jit_for, (psh, osh) = build_train_step(spec, mesh, opt_cfg)
 
     key = jax.random.key(args.seed)
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(key, spec)
         opt_state = opt_init(params, opt_cfg)
+        params, opt_state = jax.device_put((params, opt_state), (psh, osh))
 
     data = SyntheticLM(DataConfig(vocab=_vocab(spec), seq_len=args.seq,
                                   global_batch=args.batch, seed=args.seed))

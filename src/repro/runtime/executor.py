@@ -595,14 +595,6 @@ def _sm_major_perm(width: int, n_sm: int) -> np.ndarray:
     return np.arange(width).reshape(spd, n_sm).T.ravel()
 
 
-def _shard_map():
-    try:                                    # moved to jax.shard_map later
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                     # pragma: no cover
-        from jax import shard_map
-    return shard_map
-
-
 @functools.lru_cache(maxsize=None)
 def _sharded_run_positions(cfg: MachineConfig, n_warps: int, mesh, n_sm: int,
                            spd: int):
@@ -670,12 +662,12 @@ def _sharded_run_positions(cfg: MachineConfig, n_warps: int, mesh, n_sm: int,
         sm_cyc = sm_cyc + jax.lax.psum(contrib, "sm")
         return gmems, sm_cyc, ctr
 
-    sharded = _shard_map()(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P("sm"), P("sm"), P("sm"), P("sm"),
                   P(), P()),
         out_specs=(P(), P(), P("sm")),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded, donate_argnums=(8, 9))
 
 

@@ -20,7 +20,7 @@ def _loss_for(spec, profile):
     opt_cfg = OptConfig(lr=0.0, weight_decay=0.0)  # lr 0: loss only
     _, jit_for, _ = build_train_step(spec, mesh, opt_cfg, donate=False,
                                      profile=profile)
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(0), spec)
         opt = opt_init(params, opt_cfg)
         batch = {"tokens": jnp.arange(2 * 32, dtype=jnp.int32)

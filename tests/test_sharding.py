@@ -16,12 +16,12 @@ from repro.optim import OptConfig, opt_init
 def prod_mesh():
     # a (4, 2) stand-in mesh exercises the same rule logic on 8 "devices"
     if len(jax.devices()) >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+        return M.make_mesh((4, 2), ("data", "model"))
+    return M.make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_rules_shard_expected_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = M.make_mesh((1, 1), ("data", "model"))
     assert M.param_spec("embed", (49152, 960), mesh) == P("model", None)
     assert M.param_spec("layers/attn/wq", (32, 960, 960), mesh) == \
         P(None, "data", "model")
@@ -34,7 +34,7 @@ def test_param_rules_shard_expected_axes():
 
 
 def test_param_rules_drop_nondivisible_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = M.make_mesh((1, 1), ("data", "model"))
     # force axis sizes via a fake mesh dict is awkward; instead verify the
     # _fit helper directly with a production-shaped mesh mock
     class FakeMesh:
@@ -89,14 +89,18 @@ def test_decode_state_spec_long_context():
 def test_train_step_runs_on_debug_mesh(prod_mesh):
     spec = configs.reduced(configs.get("smollm_360m"))
     opt_cfg = OptConfig(lr=1e-3)
-    _, jit_for, _ = build_train_step(spec, prod_mesh, opt_cfg,
-                                     donate=False)
-    with M.use_mesh(prod_mesh):
+    _, jit_for, (psh, osh) = build_train_step(spec, prod_mesh, opt_cfg,
+                                              donate=False)
+    with jax.set_mesh(prod_mesh):
         params = api.init(jax.random.key(0), spec)
         opt_state = opt_init(params, opt_cfg)
+        # arrays made under an Auto mesh are committed replicated; the
+        # step's in_shardings accept only their own placement (host
+        # arrays, like the batch, are placed by the step itself)
+        params, opt_state = jax.device_put((params, opt_state), (psh, osh))
         B, S = 4, 32
-        batch = {"tokens": jnp.zeros((B, S), jnp.int32),
-                 "labels": jnp.ones((B, S), jnp.int32)}
+        batch = {"tokens": np.zeros((B, S), np.int32),
+                 "labels": np.ones((B, S), np.int32)}
         step = jit_for(jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch))
         p2, o2, stats = step(params, opt_state, batch)
@@ -177,7 +181,7 @@ def test_decode_state_spec_time_axis_model_fallback():
 
 def test_make_sm_mesh_on_forced_devices():
     """The mesh the sharded executor runs over, on 1 and (forced) 8
-    devices — the shimmed constructor must produce a one-axis ("sm",)
+    devices — the constructor must produce a one-axis ("sm",)
     mesh clamped to the local device count."""
     m1 = M.make_sm_mesh(1)
     assert m1.axis_names == ("sm",) and m1.devices.size == 1
@@ -189,15 +193,26 @@ def test_make_sm_mesh_on_forced_devices():
     assert big.devices.size == len(jax.devices())
 
 
-def test_make_mesh_fallback_shim(monkeypatch):
-    """Without ``jax.make_mesh`` the shim must fall back to
-    ``Mesh(mesh_utils.create_device_mesh(...))`` and build the same
-    mesh."""
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    n = len(jax.devices())
-    m = M._make_mesh((n,), ("sm",))
-    assert isinstance(m, jax.sharding.Mesh)
-    assert m.axis_names == ("sm",) and m.devices.size == n
+@pytest.mark.parametrize("n", [1, 8])
+def test_mesh_factories_state_auto_axis_types(n, monkeypatch):
+    """Every mesh factory builds Auto axes, on 1 and (forced) 8 devices.
+
+    The production meshes need 256/512 chips, so their factory is
+    checked on the axis types it asks ``jax.make_mesh`` for."""
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} (forced) devices")
+    auto = jax.sharding.AxisType.Auto
+    for mesh in (M.make_mesh((n,), ("sm",)), M.make_sm_mesh(n),
+                 M.make_debug_mesh(n)):
+        assert mesh.devices.size == n
+        assert mesh.axis_types == (auto,) * len(mesh.axis_names)
+    asked = []
+    monkeypatch.setattr(jax, "make_mesh",
+                        lambda shape, axes, axis_types=None, devices=None:
+                        asked.append((len(axes), axis_types)))
+    M.make_production_mesh()
+    M.make_production_mesh(multi_pod=True)
+    assert asked == [(2, (auto,) * 2), (3, (auto,) * 3)]
 
 
 # ------------------------- sharded executor (8 forced host devices) ----

@@ -19,7 +19,7 @@ def _train(spec, steps, ckpt_dir=None, die_at=None, restore=False,
     mesh = M.make_debug_mesh(1)
     opt_cfg = OptConfig(lr=1e-3, warmup=10)
     _, jit_for, _ = build_train_step(spec, mesh, opt_cfg, donate=False)
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(seed), spec)
         opt_state = opt_init(params, opt_cfg)
     data = SyntheticLM(DataConfig(vocab=spec.cfg.vocab, seq_len=32,
@@ -69,7 +69,7 @@ def test_crash_recovery_bit_exact(tmp_path):
 def test_greedy_decode_deterministic():
     spec = configs.reduced(configs.get("qwen3_0p6b"))
     mesh = M.make_debug_mesh(1)
-    with M.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(0), spec)
         _, jit_for, _ = build_serve_step(spec, mesh, donate=False)
         B, T = 2, 16
