@@ -34,9 +34,9 @@ import jax.numpy as jnp
 
 from ...obs import jit_call
 from .. import isa
-from .state import (EXECUTE_BACKENDS, FINISHED, READY, WAIT, Counters,
-                    MachineConfig, SMState, _BITS, _LANES, _pack, _unpack,
-                    init_state)
+from .state import (EXECUTE_BACKENDS, FINISHED, READY, TRIP_SLOT, WAIT,
+                    Counters, MachineConfig, SMState, _BITS, _LANES, _pack,
+                    _unpack, init_state)
 from .fetch_decode import Decoded, fetch_decode
 from .read import Operands, read_operands
 from .execute import EXECUTE_STAGE_BACKENDS, execute, interpret_mode
@@ -48,22 +48,32 @@ from .reference import issue_one_warp
 __all__ = [
     "EXECUTE_BACKENDS", "EXECUTE_STAGE_BACKENDS", "READY", "WAIT",
     "FINISHED", "Counters", "Decoded", "MachineConfig", "Operands",
-    "SMState", "sm_step", "fused_sm_step", "issue_one_warp", "init_state",
-    "run_block", "run_block_body", "_run_block_jit", "_BITS", "_LANES",
-    "_pack", "_unpack",
+    "SMState", "TRIP_SLOT", "sm_step", "fused_sm_step", "issue_one_warp",
+    "init_state", "run_block", "run_block_body", "split_trips", "step_fn",
+    "block_running", "_run_block_jit", "_BITS", "_LANES", "_pack",
+    "_unpack",
 ]
 
 
 def sm_step(cfg: MachineConfig, code: jnp.ndarray, lut: jnp.ndarray,
             block_dim_xy: jnp.ndarray, block_xy: jnp.ndarray,
             grid_xy: jnp.ndarray, st: SMState) -> SMState:
-    """One lockstep step: every READY warp runs the full pipeline."""
-    dec = fetch_decode(code, st)
-    ops = read_operands(cfg, lut, block_dim_xy, block_xy, grid_xy, st, dec)
-    result, nib_new = execute(cfg, dec, ops)
-    wb = write_back(cfg, st, dec, ops, result, nib_new)
-    (pc, alive, active, wstate, stack_addr, stack_type, stack_mask, sp,
-     counters) = control(cfg, st, dec, ops)
+    """One lockstep step: every READY warp runs the full pipeline.
+
+    Each stage runs under a ``jax.named_scope`` of its name, so the ops
+    of a device trace say which stage they belong to."""
+    with jax.named_scope("fetch_decode"):
+        dec = fetch_decode(code, st)
+    with jax.named_scope("read_operands"):
+        ops = read_operands(cfg, lut, block_dim_xy, block_xy, grid_xy, st,
+                            dec)
+    with jax.named_scope("execute"):
+        result, nib_new = execute(cfg, dec, ops)
+    with jax.named_scope("write_back"):
+        wb = write_back(cfg, st, dec, ops, result, nib_new)
+    with jax.named_scope("control"):
+        (pc, alive, active, wstate, stack_addr, stack_type, stack_mask, sp,
+         counters) = control(cfg, st, dec, ops)
     return SMState(
         pc=pc, alive=alive, active=active, wstate=wstate,
         stack_addr=stack_addr, stack_type=stack_type,
@@ -80,26 +90,49 @@ def run_block_body(cfg: MachineConfig, n_warps: int, code, block_dim,
     runtime passes it traced so one compiled machine serves any tenant:
     warps beyond a launch's real thread count initialize FINISHED and
     never issue, keeping counters bit-exact at any warp padding.
-    Returns ``(gmem, written-mask, Counters)`` with the store-sentinel
-    word stripped.
+    Returns ``(gmem, written-mask, loop counters)`` with the
+    store-sentinel word stripped.  The loop counters still hold the
+    :data:`TRIP_SLOT` entry; :func:`split_trips` takes the loop's trip
+    count out of them, and every caller does so before a ``Counters``
+    leaves it.
     """
     lut = jnp.asarray(isa.COND_LUT)
     st0 = init_state(cfg, n_warps, block_dim, gmem)
-
-    def cond(st: SMState):
-        return jnp.any(st.wstate != FINISHED) & \
-            (st.counters.cycles < cfg.max_cycles)
-
-    if cfg.execute_backend == "reference":
-        step = issue_one_warp
-    elif cfg.execute_backend == "pallas_fused":
-        step = functools.partial(fused_sm_step, interpret=interpret_mode())
-    else:
-        step = sm_step
-    body = functools.partial(step, cfg, code, lut, block_dim_xy,
+    body = functools.partial(step_fn(cfg), cfg, code, lut, block_dim_xy,
                              block_xy, grid_xy)
-    st = jax.lax.while_loop(cond, body, st0)
+    st = jax.lax.while_loop(functools.partial(block_running, cfg), body,
+                            st0)
     return st.gmem[:-1], st.gw[:-1], st.counters
+
+
+def split_trips(cfg: MachineConfig, n_warps: int, ctr: Counters):
+    """``(Counters, trips)`` from the machine loop's counters, batched or
+    not, on the device or on the host: the opcode vectors without
+    :data:`TRIP_SLOT`, and how many times the loop ran its step.  The
+    trips stay out of ``Counters`` because they depend on the issue
+    discipline (``reference`` visits one warp a step, the lockstep
+    backends all ``n_warps``)."""
+    warps_per_step = 1 if cfg.execute_backend == "reference" else n_warps
+    return (ctr._replace(op_issues=ctr.op_issues[..., :TRIP_SLOT],
+                         op_lanes=ctr.op_lanes[..., :TRIP_SLOT]),
+            ctr.op_issues.sum(axis=-1) // warps_per_step)
+
+
+def step_fn(cfg: MachineConfig):
+    """The step function of ``cfg.execute_backend``, called as
+    ``step(cfg, code, lut, block_dim_xy, block_xy, grid_xy, st)``."""
+    if cfg.execute_backend == "reference":
+        return issue_one_warp
+    if cfg.execute_backend == "pallas_fused":
+        return functools.partial(fused_sm_step, interpret=interpret_mode())
+    return sm_step
+
+
+def block_running(cfg: MachineConfig, st: SMState):
+    """The machine loop's condition: a warp is not FINISHED and the
+    runaway guard has not tripped."""
+    return jnp.any(st.wstate != FINISHED) & \
+        (st.counters.cycles < cfg.max_cycles)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2))
@@ -107,8 +140,9 @@ def _run_block_jit(cfg: MachineConfig, code: jnp.ndarray, block_dim: int,
                    block_dim_xy: jnp.ndarray, block_xy: jnp.ndarray,
                    grid_xy: jnp.ndarray, gmem: jnp.ndarray):
     n_warps = -(-block_dim // isa.WARP_SIZE)
-    return run_block_body(cfg, n_warps, code, block_dim, block_dim_xy,
-                          block_xy, grid_xy, gmem)
+    gm, gw, ctr = run_block_body(cfg, n_warps, code, block_dim,
+                                 block_dim_xy, block_xy, grid_xy, gmem)
+    return gm, gw, split_trips(cfg, n_warps, ctr)[0]
 
 
 def run_block(code, block_dim: int, block_xy, grid_xy, gmem,

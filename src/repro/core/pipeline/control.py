@@ -18,8 +18,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .. import isa
-from .state import FINISHED, WAIT, Counters, MachineConfig, SMState, \
-    _pack, _unpack
+from .state import FINISHED, TRIP_SLOT, WAIT, Counters, MachineConfig, \
+    SMState, _pack, _unpack
 from .fetch_decode import Decoded
 from .read import Operands
 
@@ -102,10 +102,11 @@ def control(cfg: MachineConfig, st: SMState, dec: Decoded, ops: Operands):
             1),                              # a TAKEN pop costs one cycle
         0)                                   # non-issued warps: idle
     c = st.counters
-    op_c = jnp.where(dec.exec_this, dec.op, isa.NOP)
+    # a warp that does not execute counts at TRIP_SLOT: every step adds
+    # W issues in all, which is how the loop's trips are counted
+    op_c = jnp.where(dec.exec_this, dec.op, TRIP_SLOT)
     counters = Counters(
-        op_issues=c.op_issues.at[op_c].add(
-            jnp.where(dec.exec_this, 1, 0)),
+        op_issues=c.op_issues.at[op_c].add(1),
         op_lanes=c.op_lanes.at[op_c].add(
             jnp.sum(ops.exec_mask, axis=1).astype(jnp.int32)),
         cycles=c.cycles + jnp.sum(cost),

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .. import isa
-from .state import Counters, MachineConfig, SMState
+from .state import TRIP_SLOT, Counters, MachineConfig, SMState
 from .fetch_decode import fetch_decode
 from .read import read_operands
 from .write import write_back
@@ -138,7 +138,7 @@ def fused_sm_step(cfg: MachineConfig, code: jnp.ndarray, lut: jnp.ndarray,
             s(W, D), s(W, D), s(W, D),          # stack addr/type/mask
             s(W, 32, 4), s(W, 32, R),           # pred, regs
             s(S1), s(G1), s(G1),                # smem, gmem, gw
-            s(2, isa.NUM_OPCODES), s(4),        # counter vectors/scalars
+            s(2, TRIP_SLOT + 1), s(4),          # counter vectors/scalars
         ],
         interpret=interpret,
     )(code, lut.astype(i32),

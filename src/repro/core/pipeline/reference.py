@@ -14,8 +14,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .. import isa
-from .state import FINISHED, READY, WAIT, Counters, MachineConfig, \
-    SMState, _LANES, _pack, _unpack
+from .state import FINISHED, READY, TRIP_SLOT, WAIT, Counters, \
+    MachineConfig, SMState, _LANES, _pack, _unpack
 
 
 def issue_one_warp(cfg: MachineConfig, code: jnp.ndarray,
@@ -224,9 +224,9 @@ def issue_one_warp(cfg: MachineConfig, code: jnp.ndarray,
         + jnp.where(is_smem, cfg.mem_latency_shared, 0),
         1)                                   # a TAKEN pop costs one cycle
     c = st.counters
-    op_c = jnp.where(exec_this, op, isa.NOP)
+    op_c = jnp.where(exec_this, op, TRIP_SLOT)   # see state.TRIP_SLOT
     counters = Counters(
-        op_issues=c.op_issues.at[op_c].add(jnp.where(exec_this, 1, 0)),
+        op_issues=c.op_issues.at[op_c].add(1),
         op_lanes=c.op_lanes.at[op_c].add(
             jnp.sum(exec_mask).astype(jnp.int32)),
         cycles=c.cycles + cost,

@@ -79,8 +79,21 @@ class MachineConfig:
         return self.lut_bits(n_warps) + regfile
 
 
+#: Slot past the opcodes in the loop's ``op_issues`` / ``op_lanes``
+#: vectors.  Every warp a step visits adds one issue: to its opcode if
+#: it executes, else to ``TRIP_SLOT`` (with no lanes).  So a block's
+#: issues over all slots are its loop trips times the warps per step,
+#: counted by the scatter that already runs every step, with no op of
+#: its own.  ``split_trips`` strips the slot, so a block's
+#: :class:`Counters` hold opcodes only.
+TRIP_SLOT = isa.NUM_OPCODES
+
+
 class Counters(NamedTuple):
-    """Per-block dynamic-activity counters (drive the energy model)."""
+    """Per-block dynamic-activity counters (drive the energy model).
+
+    Inside the machine loop both opcode vectors carry one more entry,
+    :data:`TRIP_SLOT`."""
     op_issues: jnp.ndarray   # (NUM_OPCODES,) instruction issues per opcode
     op_lanes: jnp.ndarray    # (NUM_OPCODES,) active-lane executions per opcode
     cycles: jnp.ndarray      # SM cycles for this block
@@ -132,8 +145,8 @@ def init_state(cfg: MachineConfig, n_warps: int, block_dim: int,
     exists = tid < block_dim
     zero = jnp.zeros((), jnp.int32)
     counters = Counters(
-        op_issues=jnp.zeros((isa.NUM_OPCODES,), jnp.int32),
-        op_lanes=jnp.zeros((isa.NUM_OPCODES,), jnp.int32),
+        op_issues=jnp.zeros((TRIP_SLOT + 1,), jnp.int32),
+        op_lanes=jnp.zeros((TRIP_SLOT + 1,), jnp.int32),
         cycles=zero, stack_ops=zero, max_sp=zero, overflow=zero)
     return SMState(
         pc=jnp.zeros((W,), jnp.int32),

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, placed from outside the library.
 
-The entry points (``gpgpu_serve.main``, ``benchmarks/run.py``,
-``chip_smoke.py``) call :func:`enable_compile_cache` once, before their
-first compile; importing the library never touches the cache.
+The entry points (``gpgpu_serve.main``, ``chip_smoke.py``) call
+:func:`enable_compile_cache` once, before their first compile;
+importing the library never touches the cache.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and the
 cache lives there: this module sets no other directory.  Otherwise the

@@ -5,12 +5,19 @@ imports nothing from the rest of :mod:`repro`, while the runtime,
 pipeline, CLIs and benchmarks all emit into it.  Three pillars:
 
 * :mod:`repro.obs.trace` — span tree over the launch lifecycle
-  (``submit → admit → queue-wait → pack → dep-resolve → dispatch →
-  device-execute → counter-sync → complete``) with Chrome-trace /
-  Perfetto export.  Process global: :data:`TRACER`.
+  (``submit → admit → queue-wait → pack → dep-resolve → dispatch
+  (prepare → device-execute per group → counter-sync → to-results) →
+  complete``) with Chrome-trace / Perfetto export, each span mirrored
+  as a ``repro.<name>`` JAX profiler annotation while tracing is on.
+  Process global: :data:`TRACER`; a ``RuntimeServer`` given its own
+  ``tracer=`` passes it down to the executor, so one tracer per server
+  sees the whole tree.
 * :mod:`repro.obs.metrics` — counters / gauges / exact-quantile
   histograms; the landing pad for what used to live in ``TRANSFERS``,
-  ``DrainStats`` and ad-hoc prints.  Process global: :data:`METRICS`.
+  ``DrainStats`` and ad-hoc prints.  Process global: :data:`METRICS`;
+  a server's own ``metrics=`` registry also receives its executor's
+  compile counts (``jit.*``) and ``shard.dispatch_groups``.  The
+  ``transfers.*`` counters stay process-wide (the ``TRANSFERS`` view).
 * :mod:`repro.obs.jitprof` — cache-miss detection and wall-ms
   attribution around the two ``jax.jit`` seams
   (:func:`jit_call`, :func:`jit_summary`, :func:`jit_delta`).

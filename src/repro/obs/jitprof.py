@@ -43,13 +43,15 @@ _SEEN: Dict[str, set] = {}
 @contextmanager
 def jit_call(site: str, jitted_fn=None, bucket: str = "default",
              key: Optional[Hashable] = None,
-             metrics: Optional[MetricsRegistry] = None):
+             metrics: Optional[MetricsRegistry] = None, span=None):
     """Time one call into ``jitted_fn`` and attribute a cache miss.
 
     ``site`` names the seam (metric ``jit.calls.<site>``); ``bucket``
     is the footprint-bucket label misses are attributed to; ``key`` is
     the caller's own trace key, used only when ``jitted_fn`` has no
-    ``_cache_size`` probe.  Wrap exactly the jitted call::
+    ``_cache_size`` probe.  A miss also sets ``jit_miss=True`` on
+    ``span``, the caller's enclosing span, when given.  Wrap exactly
+    the jitted call::
 
         with jit_call("executor.run_positions", _run_positions,
                       bucket=label, key=trace_key):
@@ -69,6 +71,8 @@ def jit_call(site: str, jitted_fn=None, bucket: str = "default",
         seen.add(key)
     m.counter(f"jit.calls.{site}").inc()
     if miss:
+        if span is not None:
+            span.set(jit_miss=True)
         m.counter("jit.cache_misses").inc()
         m.counter(f"jit.cache_misses.{bucket}").inc()
         m.histogram("jit.trace_ms").record(dt_ms)

@@ -4,11 +4,14 @@
 
 * **Spans** — nested context-managed intervals on the runtime's host
   thread (``drain`` → ``window`` → ``pack`` / ``dep-resolve`` /
-  ``dispatch`` / ``device-execute`` → ``counter-sync`` →
-  ``complete``).  Spans carry attributes (tenant, ticket, bucket,
-  n_blocks, predicted vs observed cycles) settable after entry via
-  :meth:`Span.set`, and the finished tree is inspectable as
-  ``tracer.roots`` for tests.
+  ``dispatch`` → (``prepare``, ``device-execute`` per dispatch group,
+  ``counter-sync``, ``to-results``) / ``complete``).  Spans carry
+  attributes (tenant, ticket, bucket, n_blocks, predicted vs observed
+  cycles, loop ``trips`` / ``useful_steps`` / ``width``) settable
+  after entry via :meth:`Span.set`, and the finished tree is
+  inspectable as ``tracer.roots`` for tests.  A server passes its own
+  tracer down to the executor, so one tracer holds everything its
+  drains do.
 * **Async events** — begin/end pairs keyed by ``(category, id)`` that
   may overlap arbitrarily: one per launch lifecycle, opened at
   ``submit`` and closed at completion (or drop), so a drain's trace
@@ -25,11 +28,18 @@
 ``"b"``/``"e"`` pairs on the launch track, counter samples become
 ``"C"`` events on their own named tracks.
 
+While the tracer is enabled, each span also runs under a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` when
+``jax`` is already imported (this module imports no jax), so a JAX
+profile of the process shows the runtime's phases on its own clock
+beside the device's ops.
+
 A disabled tracer (the default) returns one shared null span whose
 ``__enter__``/``set`` are no-ops — the runtime instruments its hot
-paths unconditionally and pays one boolean check when tracing is off.
-Nothing here touches a device array: enabling tracing can never add a
-host↔device transfer (pinned in ``tests/test_obs.py``).
+paths unconditionally and pays one boolean check when tracing is off,
+and enters no annotation.  Nothing here touches a device array:
+enabling tracing can never add a host↔device transfer (pinned in
+``tests/test_obs.py``).
 
 The tracer is single-threaded by design, matching the runtime's
 host-side drain loop; spans opened from other threads would interleave
@@ -38,6 +48,7 @@ on the one stack.
 from __future__ import annotations
 
 import json
+import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -60,10 +71,13 @@ class Span:
 
     ``t0``/``t1`` are seconds on the tracer's clock (perf_counter
     relative to the tracer's start).  ``set(**attrs)`` merges
-    attributes at any point before or after exit.
+    attributes at any point before or after exit.  An entered span
+    encloses a ``repro.<name>`` profiler annotation when ``jax`` is
+    loaded.
     """
 
-    __slots__ = ("tracer", "name", "attrs", "children", "t0", "t1")
+    __slots__ = ("tracer", "name", "attrs", "children", "t0", "t1",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict) -> None:
         self.tracer = tracer
@@ -72,6 +86,7 @@ class Span:
         self.children: List["Span"] = []
         self.t0: Optional[float] = None
         self.t1: Optional[float] = None
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -79,6 +94,11 @@ class Span:
 
     def __enter__(self) -> "Span":
         tr = self.tracer
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                f"repro.{self.name}")
+            self._annotation.__enter__()
         self.t0 = tr._now()
         (tr._stack[-1].children if tr._stack else tr.roots).append(self)
         tr._stack.append(self)
@@ -87,6 +107,8 @@ class Span:
     def __exit__(self, *exc) -> None:
         self.t1 = self.tracer._now()
         self.tracer._stack.pop()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class _NullSpan:

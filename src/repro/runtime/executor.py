@@ -37,8 +37,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import isa
-from ..core.pipeline import Counters, MachineConfig, run_block_body
-from ..obs import METRICS, TRACER, jit_call
+from ..core.pipeline import Counters, MachineConfig, run_block_body, \
+    split_trips
+from ..obs import METRICS, TRACER, MetricsRegistry, Tracer, jit_call
 from . import registry as reg
 from .registry import Module, ModuleRegistry
 
@@ -240,7 +241,9 @@ def _run_positions(cfg: MachineConfig, n_warps: int, codes, bdims, bd_xys,
     per-launch arrays (bucketed L); ``pos_*`` select each position's
     launch and block.  Blocks run under one vmap over the flattened
     (super-step, SM) axis, write sets merge in position order, and the
-    per-SM cycle counters accumulate on device.
+    per-SM cycle counters accumulate on device.  The counters come back
+    for every position, padding included, still holding each
+    position's loop trips (:func:`split_trips` takes them out).
     """
     def run_one(li, bxy):
         return run_block_body(cfg, n_warps, codes[li], bdims[li],
@@ -254,16 +257,18 @@ def _run_positions(cfg: MachineConfig, n_warps: int, codes, bdims, bd_xys,
         mem_i, wrt_i, li, valid = x
         return acc.at[li].set(jnp.where(wrt_i & valid, mem_i, acc[li])), None
 
-    gmems, _ = jax.lax.scan(merge, gmems,
-                            (mem, wrt, pos_launch, pos_valid))
+    with jax.named_scope("merge"):
+        gmems, _ = jax.lax.scan(merge, gmems,
+                                (mem, wrt, pos_launch, pos_valid))
     # per-SM accumulation in split hi/lo int32 lanes (x64 is disabled, so
     # there is no device int64): lo adds the low 16 bits, hi the rest.
     # Exact up to 2**15 blocks per SM per execute() — far beyond any
     # drain batch — where a single int32 would wrap at ~540 max-length
     # blocks.  report() recombines to int64.
-    cost = jnp.where(pos_valid, ctr.cycles + BLOCK_SCHED_OVERHEAD, 0)
-    sm_cyc = sm_cyc.at[0, sm_ids].add(cost >> 16) \
-                   .at[1, sm_ids].add(cost & 0xFFFF)
+    with jax.named_scope("sm_cycles"):
+        cost = jnp.where(pos_valid, ctr.cycles + BLOCK_SCHED_OVERHEAD, 0)
+        sm_cyc = sm_cyc.at[0, sm_ids].add(cost >> 16) \
+                       .at[1, sm_ids].add(cost & 0xFFFF)
     return gmems, sm_cyc, ctr
 
 
@@ -285,14 +290,31 @@ class DeviceGrid:
     arrays (usable as the next launch's input — stream chaining), and
     JAX's async dispatch keeps the host free until ``to_results`` or
     ``report`` materialize numpy values.
+
+    ``ctrs`` stacks every dispatch group's loop counters whole:
+    padding included, each position's loop trips still in them, taken
+    out by ``split`` (:func:`split_trips` of the run's config) after the
+    one batched fetch.  ``groups`` holds per group ``(keep, valid,
+    span)``: the group's rows of its real blocks in block order, which
+    rows are real blocks, and its ``device-execute`` span.  Padding is
+    stripped on the host too, so a group costs the device nothing
+    beyond its run; with ``tracer`` enabled each group's span gets
+    ``trips``, ``useful_steps`` and ``width``.
     """
 
-    def __init__(self, *, gmems, ctrs: Counters, sm_cyc, n_sm: int,
+    def __init__(self, *, gmems, ctrs: Counters, split, sm_cyc, n_sm: int,
                  n_steps: int, launch_offsets: Sequence[int],
-                 launch_blocks: Sequence[int], orig_lens: Sequence[int]):
+                 launch_blocks: Sequence[int], orig_lens: Sequence[int],
+                 groups: Sequence[tuple],
+                 tracer: Optional[Tracer] = None):
         self._gmems = gmems              # (L_bucket, G) device
-        self._ctrs = ctrs                # Counters stacked over positions
+        self._ctrs = ctrs                # loop counters over positions
+        self._split = split
         self._sm_cyc = sm_cyc            # (n_sm,) device
+        self._groups = list(groups)      # (keep, valid, span) per group
+        #: first row of each group in ``ctrs``, and the end
+        self._starts = np.cumsum([0] + [len(v) for _, v, _ in groups])
+        self._tracer = TRACER if tracer is None else tracer
         self.n_sm = n_sm
         self.n_steps = n_steps
         self._offsets = list(launch_offsets)
@@ -300,6 +322,7 @@ class DeviceGrid:
         self._orig_lens = list(orig_lens)
         self._gmem_views: dict = {}
         self._host: Optional[tuple] = None
+        self._steps: Optional[List[dict]] = None
         self._results: dict = {}
 
     @property
@@ -328,10 +351,36 @@ class DeviceGrid:
         (six counter leaves + the SM-cycle lanes)."""
         if self._host is None:
             _transfer("counter_syncs")
-            with TRACER.span("counter-sync", n_sm=self.n_sm,
-                             n_blocks=int(sum(self._blocks))):
-                self._host = jax.device_get((self._ctrs, self._sm_cyc))
-        return self._host
+            with self._tracer.span("counter-sync", n_sm=self.n_sm,
+                                   n_blocks=int(sum(self._blocks))):
+                c, sm_cyc = jax.device_get((self._ctrs, self._sm_cyc))
+            c, trips = self._split(c)
+            # strip the padding: counter row == global block position
+            rows = np.concatenate([start + keep for start, (keep, _, _)
+                                   in zip(self._starts, self._groups)])
+            self._host = (jax.tree.map(lambda x: x[rows], c), sm_cyc,
+                          trips)
+            if self._tracer.enabled:
+                for (_, _, sp), steps in zip(self._groups,
+                                             self.loop_steps()):
+                    sp.set(**steps)
+        return self._host[:2]
+
+    def loop_steps(self) -> List[dict]:
+        """Per dispatch group, from the batched fetch: ``trips``, the
+        batched loop's trips (the most of any position: padded
+        duplicates run too); ``useful_steps``, the trips of its valid
+        positions summed; and ``width``, its positions.  A group ran
+        ``trips * width`` block-slot steps, ``useful_steps`` of them
+        for real blocks.  Memoized, like the fetch."""
+        if self._steps is None:
+            self._host_fetch()
+            per_group = np.split(self._host[2], self._starts[1:-1])
+            self._steps = [
+                {"trips": int(t.max()), "useful_steps": int(t[valid].sum()),
+                 "width": len(t)}
+                for t, (_, valid, _) in zip(per_group, self._groups)]
+        return self._steps
 
     def report(self) -> MultiSMReport:
         """Executed per-SM cycle counters (batched host fetch).
@@ -370,6 +419,10 @@ class DeviceGrid:
         if host_gmem in self._results:
             return self._results[host_gmem]
         c, _ = self._host_fetch()
+        with self._tracer.span("to-results", n_launches=self.n_launches):
+            return self._to_results(c, host_gmem)
+
+    def _to_results(self, c: Counters, host_gmem: bool) -> List[GridResult]:
         cycles = np.asarray(c.cycles, np.int64)
         op_issues = np.asarray(c.op_issues, np.int64)
         op_lanes = np.asarray(c.op_lanes, np.int64)
@@ -400,7 +453,8 @@ def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
             cfg: MachineConfig = MachineConfig(), chunk: int = 8,
             pad_warps: Optional[int] = None,
             registry: Optional[ModuleRegistry] = None,
-            shard_sm: bool = False) -> DeviceGrid:
+            shard_sm: bool = False, tracer: Optional[Tracer] = None,
+            metrics: Optional[MetricsRegistry] = None) -> DeviceGrid:
     """Execute the blocks of ``launches`` round-robin across ``n_sm`` SMs.
 
     Blocks may not communicate (true of the paper's benchmarks); write
@@ -415,82 +469,93 @@ def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
     the placement contract) — bit-exact with the single-device path,
     falling back to it when only one device exists or ``n_sm`` does not
     divide over the devices.
+
+    Spans go to ``tracer`` and compile counts to ``metrics`` (default:
+    the process globals): ``prepare`` (binaries, memory and schedule
+    onto the device), then one ``device-execute`` per dispatch group
+    (its schedule arrays and its enqueue; ``jit_miss`` on a compile).
     """
     if not launches:
         raise ValueError("execute() needs at least one launch")
-    registry = registry or _default_registry
-    mods = [registry.as_module(l.code) for l in launches]
-    code_len = max(m.padded_len for m in mods)
-    n_l = len(launches)
-    l_bucket = bucket_launches(n_l)
+    tracer = TRACER if tracer is None else tracer
+    metrics = METRICS if metrics is None else metrics
+    with tracer.span("prepare", n_launches=len(launches)):
+        registry = registry or _default_registry
+        mods = [registry.as_module(l.code) for l in launches]
+        code_len = max(m.padded_len for m in mods)
+        n_l = len(launches)
+        l_bucket = bucket_launches(n_l)
 
-    bdims = np.zeros(l_bucket, np.int32)
-    bd_xys = np.zeros((l_bucket, 2), np.int32)
-    grid_xys = np.ones((l_bucket, 2), np.int32)
-    codes = np.zeros((l_bucket, code_len, isa.NUM_FIELDS), np.int32)
-    codes[:, :, isa.F_OP] = isa.EXIT      # padding launches trap to EXIT
-    orig_lens, gmem_parts = [], []
-    pos_launch_l, pos_bxy_l = [], []
-    offsets, nblocks = [], []
-    for i, (launch, mod) in enumerate(zip(launches, mods)):
-        bdx, bdy = _norm_block_dim(launch.block_dim)
-        bdims[i] = bdx * bdy
-        bd_xys[i] = (bdx, bdy)
-        grid_xys[i] = launch.grid
-        codes[i] = reg.pad_code(mod.code, code_len)
-        g = launch.gmem
-        orig_lens.append(int(g.shape[0]))
-        gmem_parts.append(g)
-        bxys = _block_positions(launch.grid)
-        if len(bxys) == 0:
+        bdims = np.zeros(l_bucket, np.int32)
+        bd_xys = np.zeros((l_bucket, 2), np.int32)
+        grid_xys = np.ones((l_bucket, 2), np.int32)
+        codes = np.zeros((l_bucket, code_len, isa.NUM_FIELDS), np.int32)
+        codes[:, :, isa.F_OP] = isa.EXIT      # padding launches trap to EXIT
+        orig_lens, gmem_parts = [], []
+        pos_launch_l, pos_bxy_l = [], []
+        offsets, nblocks = [], []
+        for i, (launch, mod) in enumerate(zip(launches, mods)):
+            bdx, bdy = _norm_block_dim(launch.block_dim)
+            bdims[i] = bdx * bdy
+            bd_xys[i] = (bdx, bdy)
+            grid_xys[i] = launch.grid
+            codes[i] = reg.pad_code(mod.code, code_len)
+            g = launch.gmem
+            orig_lens.append(int(g.shape[0]))
+            gmem_parts.append(g)
+            bxys = _block_positions(launch.grid)
+            if len(bxys) == 0:
+                raise ValueError(
+                    f"launch {i} ({mod.name}) has an empty grid "
+                    f"{launch.grid} (0 blocks)")
+            offsets.append(sum(nblocks))
+            nblocks.append(len(bxys))
+            pos_launch_l.append(np.full(len(bxys), i, np.int32))
+            pos_bxy_l.append(bxys)
+
+        g_width = reg.bucket_gmem_len(max(orig_lens))
+        gmems = jnp.stack(
+            [_pad_gmem_device(g, g_width) for g in gmem_parts]
+            + [jnp.zeros((g_width,), jnp.int32)] * (l_bucket - n_l))
+
+        warps_needed = max(warps_for(int(b)) for b in bdims[:n_l])
+        n_warps = pad_warps or warps_needed
+        if n_warps < warps_needed:
             raise ValueError(
-                f"launch {i} ({mod.name}) has an empty grid "
-                f"{launch.grid} (0 blocks)")
-        offsets.append(sum(nblocks))
-        nblocks.append(len(bxys))
-        pos_launch_l.append(np.full(len(bxys), i, np.int32))
-        pos_bxy_l.append(bxys)
+                f"pad_warps={pad_warps} < {warps_needed} warps required by "
+                f"the widest launch ({int(bdims[:n_l].max())} threads) — "
+                "threads beyond the padding would silently never run")
+        pos_launch = np.concatenate(pos_launch_l)
+        pos_bxy = np.concatenate(pos_bxy_l)
+        n_blocks = len(pos_launch)
+        if -(-n_blocks // n_sm) > 1 << 15:
+            # the split hi/lo per-SM accumulator in _run_positions is exact
+            # to 2**15 blocks per SM; beyond that the lo lane could wrap
+            raise ValueError(
+                f"{n_blocks} blocks on {n_sm} SMs exceeds the per-SM cycle "
+                f"accumulator bound of {1 << 15} blocks/SM per execute() — "
+                "split the grid across multiple execute() calls")
 
-    g_width = reg.bucket_gmem_len(max(orig_lens))
-    gmems = jnp.stack(
-        [_pad_gmem_device(g, g_width) for g in gmem_parts]
-        + [jnp.zeros((g_width,), jnp.int32)] * (l_bucket - n_l))
+        # schedule: position p -> SM p % n_sm, super-step p // n_sm.  Each
+        # dispatch group pads to a pow2-bucketed width with masked duplicate
+        # blocks, so ragged tails and small grids together cost at most
+        # log2(chunk)+1 cached traces — instead of either retracing per
+        # ragged size (the seed behaviour) or simulating up to width-1
+        # discarded blocks (full-width padding); waste is bounded below the
+        # group's real block count.
+        sm_ids_all = (np.arange(n_blocks) % n_sm).astype(np.int32)
+        spd_max = max(1, chunk // n_sm)          # super-steps per dispatch
 
-    warps_needed = max(warps_for(int(b)) for b in bdims[:n_l])
-    n_warps = pad_warps or warps_needed
-    if n_warps < warps_needed:
-        raise ValueError(
-            f"pad_warps={pad_warps} < {warps_needed} warps required by "
-            f"the widest launch ({int(bdims[:n_l].max())} threads) — "
-            "threads beyond the padding would silently never run")
-    pos_launch = np.concatenate(pos_launch_l)
-    pos_bxy = np.concatenate(pos_bxy_l)
-    n_blocks = len(pos_launch)
-    if -(-n_blocks // n_sm) > 1 << 15:
-        # the split hi/lo per-SM accumulator in _run_positions is exact
-        # to 2**15 blocks per SM; beyond that the lo lane could wrap
-        raise ValueError(
-            f"{n_blocks} blocks on {n_sm} SMs exceeds the per-SM cycle "
-            f"accumulator bound of {1 << 15} blocks/SM per execute() — "
-            "split the grid across multiple execute() calls")
-
-    # schedule: position p -> SM p % n_sm, super-step p // n_sm.  Each
-    # dispatch group pads to a pow2-bucketed width with masked duplicate
-    # blocks, so ragged tails and small grids together cost at most
-    # log2(chunk)+1 cached traces — instead of either retracing per
-    # ragged size (the seed behaviour) or simulating up to width-1
-    # discarded blocks (full-width padding); waste is bounded below the
-    # group's real block count.
-    sm_ids_all = (np.arange(n_blocks) % n_sm).astype(np.int32)
-    spd_max = max(1, chunk // n_sm)          # super-steps per dispatch
-
-    mesh = shard_plan(n_sm) if shard_sm else None
-    codes_d = jnp.asarray(codes)
-    bdims_d = jnp.asarray(bdims)
-    bd_xys_d = jnp.asarray(bd_xys)
-    grid_xys_d = jnp.asarray(grid_xys)
-    sm_cyc = jnp.zeros((2, n_sm), jnp.int32)    # (hi, lo) split lanes
-    ctr_groups = []
+        mesh = shard_plan(n_sm) if shard_sm else None
+        codes_d = jnp.asarray(codes)
+        bdims_d = jnp.asarray(bdims)
+        bd_xys_d = jnp.asarray(bd_xys)
+        grid_xys_d = jnp.asarray(grid_xys)
+        sm_cyc = jnp.zeros((2, n_sm), jnp.int32)    # (hi, lo) split lanes
+        n_dev = int(mesh.devices.size) if mesh is not None else 1
+        bucket = f"c{code_len}g{g_width}w{n_warps}sm{n_sm}" + \
+            (f"x{n_dev}dev" if mesh is not None else "")
+    outs, groups = [], []
     lo = 0
     while lo < n_blocks:
         spd = spd_max
@@ -498,66 +563,65 @@ def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
             spd //= 2
         width = spd * n_sm
         take = min(width, n_blocks - lo)
-        pl = pos_launch[lo:lo + take]
-        pb = pos_bxy[lo:lo + take]
-        sm = sm_ids_all[lo:lo + take]
-        if take < width:
-            pad = width - take
-            pl = np.concatenate([pl, np.zeros(pad, np.int32)])
-            pb = np.concatenate([pb, np.zeros((pad, 2), np.int32)])
-            sm = np.concatenate([sm, np.zeros(pad, np.int32)])
-        valid = np.arange(width) < take
-        if mesh is not None:
-            # device-parallel dispatch: permute the group to SM-major
-            # order so P("sm") places each SM's blocks (and counter) on
-            # its owning device — placement matches the p % n_sm
-            # attribution by construction
-            perm = _sm_major_perm(width, n_sm)
-            inv = np.argsort(perm)
-            runner = _sharded_run_positions(cfg, n_warps, mesh, n_sm, spd)
-            group = (jnp.asarray(pl[perm]), jnp.asarray(pb[perm]),
-                     jnp.asarray(valid[perm]),
-                     jnp.asarray(perm.astype(np.int32)))
-            n_dev = int(mesh.devices.size)
-            bucket = f"c{code_len}g{g_width}w{n_warps}sm{n_sm}x{n_dev}dev"
-            METRICS.counter("shard.dispatch_groups").inc()
-            with TRACER.span("device-execute", bucket=bucket, width=width,
-                             n_blocks=take, n_sm=n_sm, n_devices=n_dev), \
-                 jit_call("executor.run_positions_sharded", runner,
-                          bucket=bucket,
-                          key=(cfg, n_warps, l_bucket, code_len, g_width,
-                               width, n_sm, n_dev)):
-                gmems, sm_cyc, ctr = runner(
-                    codes_d, bdims_d, bd_xys_d, grid_xys_d, *group,
-                    gmems, sm_cyc)
-            # gather the slot-sharded per-block counters back to global
-            # block-position order (and strip this group's padding)
-            take_idx = jnp.asarray(inv[:take])
-            ctr_groups.append(jax.tree.map(lambda x: x[take_idx], ctr))
-            lo += take
-            continue
-        group = (jnp.asarray(pl), jnp.asarray(pb), jnp.asarray(valid),
-                 jnp.asarray(sm))
-        bucket = f"c{code_len}g{g_width}w{n_warps}sm{n_sm}"
-        with TRACER.span("device-execute", bucket=bucket, width=width,
-                         n_blocks=take, n_sm=n_sm), \
-             jit_call("executor.run_positions", _run_positions,
-                      bucket=bucket,
-                      key=(cfg, n_warps, l_bucket, code_len, g_width,
-                           width, n_sm)):
-            gmems, sm_cyc, ctr = _run_positions(
-                cfg, n_warps, codes_d, bdims_d, bd_xys_d, grid_xys_d,
-                *group, gmems, sm_cyc)
-        # strip this group's padding so stacked counter index == global
-        # block position
-        ctr_groups.append(jax.tree.map(lambda x: x[:take], ctr))
+        with tracer.span("device-execute", bucket=bucket, width=width,
+                         n_blocks=take, n_sm=n_sm, n_devices=n_dev) as sp:
+            pl = pos_launch[lo:lo + take]
+            pb = pos_bxy[lo:lo + take]
+            sm = sm_ids_all[lo:lo + take]
+            if take < width:
+                pad = width - take
+                pl = np.concatenate([pl, np.zeros(pad, np.int32)])
+                pb = np.concatenate([pb, np.zeros((pad, 2), np.int32)])
+                sm = np.concatenate([sm, np.zeros(pad, np.int32)])
+            valid = np.arange(width) < take
+            if mesh is not None:
+                # device-parallel dispatch: permute the group to SM-major
+                # order so P("sm") places each SM's blocks (and counter)
+                # on its owning device — placement matches the p % n_sm
+                # attribution by construction
+                perm = _sm_major_perm(width, n_sm)
+                inv = np.argsort(perm)
+                runner = _sharded_run_positions(cfg, n_warps, mesh, n_sm,
+                                                spd)
+                valid = valid[perm]
+                group = (jnp.asarray(pl[perm]), jnp.asarray(pb[perm]),
+                         jnp.asarray(valid),
+                         jnp.asarray(perm.astype(np.int32)))
+                metrics.counter("shard.dispatch_groups").inc()
+                with jit_call("executor.run_positions_sharded", runner,
+                              bucket=bucket,
+                              key=(cfg, n_warps, l_bucket, code_len, g_width,
+                                   width, n_sm, n_dev),
+                              metrics=metrics, span=sp):
+                    gmems, sm_cyc, ctr = runner(
+                        codes_d, bdims_d, bd_xys_d, grid_xys_d, *group,
+                        gmems, sm_cyc)
+                # the slot-sharded rows of the real blocks, in global
+                # block-position order
+                keep = inv[:take]
+            else:
+                group = (jnp.asarray(pl), jnp.asarray(pb),
+                         jnp.asarray(valid), jnp.asarray(sm))
+                with jit_call("executor.run_positions", _run_positions,
+                              bucket=bucket,
+                              key=(cfg, n_warps, l_bucket, code_len, g_width,
+                                   width, n_sm),
+                              metrics=metrics, span=sp):
+                    gmems, sm_cyc, ctr = _run_positions(
+                        cfg, n_warps, codes_d, bdims_d, bd_xys_d,
+                        grid_xys_d, *group, gmems, sm_cyc)
+                keep = np.arange(take)
+        outs.append(ctr)
+        groups.append((keep, valid, sp))
         lo += take
 
-    ctrs = jax.tree.map(lambda *xs: jnp.concatenate(xs), *ctr_groups) \
-        if len(ctr_groups) > 1 else ctr_groups[0]
-    return DeviceGrid(gmems=gmems, ctrs=ctrs, sm_cyc=sm_cyc, n_sm=n_sm,
-                      n_steps=-(-n_blocks // n_sm), launch_offsets=offsets,
-                      launch_blocks=nblocks, orig_lens=orig_lens)
+    ctrs = jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs) \
+        if len(outs) > 1 else outs[0]
+    return DeviceGrid(gmems=gmems, ctrs=ctrs,
+                      split=functools.partial(split_trips, cfg, n_warps),
+                      sm_cyc=sm_cyc, n_sm=n_sm, n_steps=-(-n_blocks // n_sm),
+                      launch_offsets=offsets, launch_blocks=nblocks,
+                      orig_lens=orig_lens, groups=groups, tracer=tracer)
 
 
 def shard_plan(n_sm: int):
@@ -641,25 +705,28 @@ def _sharded_run_positions(cfg: MachineConfig, n_warps: int, mesh, n_sm: int,
             return (last.at[li].set(jnp.where(newer, pid, last[li])),
                     val.at[li].set(jnp.where(newer, mem_i, val[li]))), None
 
-        (last, val), _ = jax.lax.scan(
-            merge, (last0, val0), (mem, wrt, pos_launch, pos_valid,
-                                   pos_ids))
-        # cross-device combine: the device holding the globally latest
-        # write wins; everyone else contributes 0 to the psum
-        gmax = jax.lax.pmax(last, "sm")
-        win = jnp.where((last == gmax) & (gmax >= 0), val, 0)
-        gmems = jnp.where(gmax >= 0, jax.lax.psum(win, "sm"), gmems)
+        with jax.named_scope("merge"):
+            (last, val), _ = jax.lax.scan(
+                merge, (last0, val0), (mem, wrt, pos_launch, pos_valid,
+                                       pos_ids))
+            # cross-device combine: the device holding the globally
+            # latest write wins; everyone else contributes 0 to the psum
+            gmax = jax.lax.pmax(last, "sm")
+            win = jnp.where((last == gmax) & (gmax >= 0), val, 0)
+            gmems = jnp.where(gmax >= 0, jax.lax.psum(win, "sm"), gmems)
 
         # per-SM counters: slots q of local SM k map to global SM
         # (device * sm_per_dev + k) — accumulation never leaves the
         # owning device; one tiny psum folds the per-device partials
-        sm0 = jax.lax.axis_index("sm") * sm_per_dev
-        local_sm = sm0 + jnp.arange(local_w, dtype=jnp.int32) // spd
-        cost = jnp.where(pos_valid, ctr.cycles + BLOCK_SCHED_OVERHEAD, 0)
-        contrib = jnp.zeros((2, n_sm), jnp.int32) \
-            .at[0, local_sm].add(cost >> 16) \
-            .at[1, local_sm].add(cost & 0xFFFF)
-        sm_cyc = sm_cyc + jax.lax.psum(contrib, "sm")
+        with jax.named_scope("sm_cycles"):
+            sm0 = jax.lax.axis_index("sm") * sm_per_dev
+            local_sm = sm0 + jnp.arange(local_w, dtype=jnp.int32) // spd
+            cost = jnp.where(pos_valid, ctr.cycles + BLOCK_SCHED_OVERHEAD,
+                             0)
+            contrib = jnp.zeros((2, n_sm), jnp.int32) \
+                .at[0, local_sm].add(cost >> 16) \
+                .at[1, local_sm].add(cost & 0xFFFF)
+            sm_cyc = sm_cyc + jax.lax.psum(contrib, "sm")
         return gmems, sm_cyc, ctr
 
     sharded = jax.shard_map(

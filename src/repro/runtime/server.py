@@ -812,7 +812,9 @@ class RuntimeServer:
                                         chunk=self.chunk,
                                         pad_warps=sb.pad_warps,
                                         registry=self.registry,
-                                        shard_sm=self.shard_sm)
+                                        shard_sm=self.shard_sm,
+                                        tracer=self.tracer,
+                                        metrics=self.metrics)
                         sub_results = dg.to_results(
                             host_gmem=not self.resident_gmem)
                 except Exception as e:
@@ -907,6 +909,11 @@ class RuntimeServer:
                 rep = dg.report()
                 disp_sp.set(observed_cycles=rep.kernel_cycles,
                             max_sp=rep.max_sp)
+                if self.tracer.enabled:
+                    # the dispatch groups' loop trips, summed
+                    steps = dg.loop_steps()
+                    disp_sp.set(**{k: sum(g[k] for g in steps) for k in
+                                   ("trips", "useful_steps", "width")})
                 if rep.overflow:
                     disp_sp.set(stack_overflow=True)
                 per_sm += rep.per_sm_cycles

@@ -235,8 +235,9 @@ class QueuedLaunch:
             if loop is not None and loop.running:
                 loop.wait_for(self)
             else:
-                with TRACER.span("future-wait", ticket=self.ticket,
-                                 tenant=self.client):
+                with self._server.tracer.span(
+                        "future-wait", ticket=self.ticket,
+                        tenant=self.client):
                     try:
                         self._server.drain()
                     except Exception:

@@ -228,20 +228,19 @@ def test_jit_call_cache_size_probe(request):
 
 @pytest.fixture
 def tracer():
-    """The process-global tracer, enabled for one test — the executor's
-    device-execute / counter-sync spans emit into this one, so the full
-    nesting is only visible here (a server-local Tracer would see only
-    the server's own phases)."""
-    tr = obs.TRACER.start()
+    """A server-local tracer, enabled for one test: the server passes it
+    down to the executor, so it sees the full nesting, device-execute /
+    counter-sync included."""
+    tr = obs.Tracer().start()
     yield tr
     tr.stop()
-    tr.clear()
 
 
-def _dependent_drain(metrics=None):
+def _dependent_drain(metrics=None, tracer=None):
     """3 chained launches, max_batch=1 → a 3-window dependent drain."""
     code, grid, bd, g0 = _launch_args()
-    srv = rt.RuntimeServer(n_sm=2, max_batch=1, metrics=metrics)
+    srv = rt.RuntimeServer(n_sm=2, max_batch=1, metrics=metrics,
+                           tracer=tracer)
     f1 = srv.submit_future(code, grid, bd, g0.copy(), client="t0")
     f2 = srv.submit_future(code, grid, bd, f1, client="t1")
     f3 = srv.submit_future(code, grid, bd, f2, client="t1")
@@ -252,7 +251,7 @@ def _dependent_drain(metrics=None):
 def test_span_tree_three_window_dependent_drain(tracer):
     tr = tracer
     m = obs.MetricsRegistry()
-    srv, futs, results, stats = _dependent_drain(metrics=m)
+    srv, futs, results, stats = _dependent_drain(metrics=m, tracer=tr)
     tr.stop()
     assert stats.n_windows == 3 and stats.n_launches == 3
 
@@ -315,7 +314,7 @@ def test_span_tree_three_window_dependent_drain(tracer):
 
 def test_chrome_trace_schema(tmp_path, tracer):
     tr = tracer
-    _dependent_drain()
+    _dependent_drain(tracer=tr)
     tr.stop()
     out = tmp_path / "trace.json"
     doc = tr.export(str(out))
@@ -357,7 +356,8 @@ def test_chrome_trace_counters_and_shed_pairs(tmp_path, tracer):
     import time as _time
     tr = tracer
     code, grid, bd, g0 = _launch_args()
-    srv = rt.RuntimeServer(n_sm=1, metrics=obs.MetricsRegistry())
+    srv = rt.RuntimeServer(n_sm=1, metrics=obs.MetricsRegistry(),
+                           tracer=tr)
     doomed = srv.submit_future(code, grid, bd, g0.copy(), client="late",
                                deadline_s=0.0)
     ok = srv.submit_future(code, grid, bd, g0.copy(), client="ontime")
@@ -445,7 +445,7 @@ def test_instrumented_path_bit_exact_and_transfer_free():
 
 def test_instrumented_matches_sequential_oracle(tracer):
     code, grid, bd, g0 = _launch_args()
-    _srv, futs, results, _stats = _dependent_drain()
+    _srv, futs, results, _stats = _dependent_drain(tracer=tracer)
     tracer.stop()
     want = scheduler.run_grid(code, grid, bd, g0.copy())
     np.testing.assert_array_equal(results[futs[0].ticket].gmem,
