@@ -1,11 +1,16 @@
 """Read stage of the all-warp pipeline.
 
 The parallel source-operand units of §4.2, widened to the full (W, 32)
-lane grid: register-file gathers for up to three source operands per
+lane grid: register-file reads for up to three source operands per
 warp (the third gated by ``num_read_operands``), guard-predicate LUT
 evaluation, special-register materialization for S2R, and the memory
 read ports (global + shared loads are issued here so the execute stage
 is a pure function of operands — that is what makes it pluggable).
+
+The register and predicate files are read by a one-hot masked sum over
+their minor axis, the mirror of the write stage's select: each
+(warp, lane) reads one column of its own row, so exactly one term of the
+sum is nonzero and the int32 result is exact.
 """
 from __future__ import annotations
 
@@ -31,9 +36,15 @@ class Operands(NamedTuple):
     ld_s: jnp.ndarray       # (W, 32) int32 — shared load data
 
 
-def _gather_reg(regs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """regs (W, 32, R), idx (W,) -> (W, 32) register column per warp."""
-    return jnp.take_along_axis(regs, idx[:, None, None], axis=2)[..., 0]
+def _read_col(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """table (W, 32, R), idx (W,) -> (W, 32) column ``idx[w]`` per warp;
+    an index outside the file reads 0.
+
+    The column iota is built at trace time (Pallas kernel bodies reject
+    captured array constants — fused.py traces this stage in-kernel)."""
+    cols = jnp.arange(table.shape[2], dtype=jnp.int32)
+    hit = cols == idx[:, None, None]
+    return jnp.sum(jnp.where(hit, table, 0), axis=2, dtype=table.dtype)
 
 
 def read_operands(cfg: MachineConfig, lut: jnp.ndarray,
@@ -45,8 +56,7 @@ def read_operands(cfg: MachineConfig, lut: jnp.ndarray,
     arange_w = jnp.arange(W, dtype=jnp.int32)
 
     # ---- guard / condition evaluation (predicate LUT of Fig. 2) -------
-    nib = jnp.take_along_axis(st.pred, dec.gpred[:, None, None],
-                              axis=2)[..., 0]            # (W, 32)
+    nib = _read_col(st.pred, dec.gpred)                  # (W, 32)
     cond_val = lut[dec.gcond[:, None], nib]              # (W, 32) bool
     gm = jnp.where(dec.guarded[:, None], cond_val, True)
     exec_mask = dec.active & st.alive & gm & dec.exec_this[:, None]
@@ -54,10 +64,10 @@ def read_operands(cfg: MachineConfig, lut: jnp.ndarray,
     # ---- register-file read ports --------------------------------------
     imm_col = dec.imm[:, None]
     s1 = jnp.where((dec.flags[:, None] & isa.FLAG_SRC1_IMM) != 0, imm_col,
-                   _gather_reg(st.regs, dec.src1))
+                   _read_col(st.regs, dec.src1))
     s2 = jnp.where((dec.flags[:, None] & isa.FLAG_SRC2_IMM) != 0, imm_col,
-                   _gather_reg(st.regs, dec.src2))
-    s3 = _gather_reg(st.regs, dec.src3) if cfg.num_read_operands >= 3 \
+                   _read_col(st.regs, dec.src2))
+    s3 = _read_col(st.regs, dec.src3) if cfg.num_read_operands >= 3 \
         else jnp.zeros_like(s1)
 
     # ---- special-register values for S2R -------------------------------

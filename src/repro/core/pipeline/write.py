@@ -1,13 +1,18 @@
 """Write stage of the all-warp pipeline.
 
-Commits one lockstep issue for every warp at once: register-file and
-predicate-file writebacks are (W, 32) masked column scatters; global and
-shared stores from all warps flatten to one scatter each, with inactive
-lanes redirected to the sentinel word (they rewrite its current value,
-so the scatter needs no branch).  Cross-warp stores to the same address
-in one step have an implementation-defined winner (XLA scatter with
-duplicate indices) — the CUDA-race semantics the paper's race-free
-programs never observe; CUDA gives no stronger guarantee either.
+Commits one lockstep issue for every warp at once.  The register file
+and the predicate file are written by a one-hot select over their minor
+axis: each (warp, lane) writes at most one column of its own row
+(``dst`` / ``pdst`` per warp), so the write is one dense ``where`` over
+the (W, 32, R) file, with no scatter.  A TPU scatter pays for every
+update, the select only for the file's words, which at these sizes is
+the cheaper of the two at every warp count.  Global and shared stores
+from all warps flatten to one scatter each, with inactive lanes
+redirected to the sentinel word (they rewrite its current value, so the
+scatter needs no branch).  Cross-warp stores to the same address in one
+step have an implementation-defined winner (XLA scatter with duplicate
+indices) — the CUDA-race semantics the paper's race-free programs never
+observe; CUDA gives no stronger guarantee either.
 """
 from __future__ import annotations
 
@@ -28,35 +33,34 @@ class Written(NamedTuple):
     gw: jnp.ndarray
 
 
+def _write_col(table: jnp.ndarray, idx: jnp.ndarray, wr: jnp.ndarray,
+               val: jnp.ndarray) -> jnp.ndarray:
+    """table (W, 32, R), idx (W,), wr / val (W, 32) -> ``table`` with
+    ``val`` in column ``idx[w]`` of every lane where ``wr``; an index
+    outside the file writes nothing.
+
+    The column iota is built at trace time: this stage is also traced
+    inside the fused Pallas kernel, which rejects captured array
+    constants (fused.py)."""
+    cols = jnp.arange(table.shape[2], dtype=jnp.int32)
+    hit = wr[..., None] & (cols == idx[:, None, None])
+    return jnp.where(hit, val[..., None], table)
+
+
 def write_back(cfg: MachineConfig, st: SMState, dec: Decoded,
                ops: Operands, result: jnp.ndarray,
                nib_new: jnp.ndarray) -> Written:
-    W = st.pc.shape[0]
     G = st.gmem.shape[0] - 1
-    arange_w = jnp.arange(W, dtype=jnp.int32)
-
-    # lane iota + scalar opcode bitmask instead of module-level array
-    # constants: this stage is also traced inside the fused Pallas
-    # kernel, which rejects captured array constants (fused.py)
-    lanes = jnp.arange(isa.WARP_SIZE, dtype=jnp.int32)
 
     # ---- register writeback (opcode-class bitmask test, per warp) ------
     has_dst = ((jnp.int32(isa.WRITES_REG_MASK) >> dec.op) & 1) != 0
-    wr = ops.exec_mask & has_dst[:, None]
-    old_dcol = jnp.take_along_axis(st.regs, dec.dst[:, None, None],
-                                   axis=2)[..., 0]
-    new_dcol = jnp.where(wr, result, old_dcol)
-    regs = st.regs.at[arange_w[:, None], lanes[None, :],
-                      dec.dst[:, None]].set(new_dcol)
+    regs = _write_col(st.regs, dec.dst, ops.exec_mask & has_dst[:, None],
+                      result)
 
     # ---- predicate writeback -------------------------------------------
     is_setp = dec.op == isa.ISETP
-    old_pcol = jnp.take_along_axis(st.pred, dec.pdst[:, None, None],
-                                   axis=2)[..., 0]
-    new_pcol = jnp.where(ops.exec_mask & is_setp[:, None], nib_new,
-                         old_pcol)
-    pred = st.pred.at[arange_w[:, None], lanes[None, :],
-                      dec.pdst[:, None]].set(new_pcol)
+    pred = _write_col(st.pred, dec.pdst, ops.exec_mask & is_setp[:, None],
+                      nib_new)
 
     # global / shared stores (inactive lanes write the sentinel word)
     st_g = ops.exec_mask & (dec.op == isa.STG)[:, None]

@@ -13,6 +13,7 @@ so the kernels are called with ``interpret=False`` directly, and the
 pipeline's platform decision is steered from the test.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +101,24 @@ def test_run_positions_compiles(one_chip, backend, monkeypatch):
     print(mem)
     assert mem.temp_size_in_bytes < 64 << 20
     assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+
+
+def test_run_positions_register_files_not_scattered(one_chip, monkeypatch):
+    """The step writes the register and predicate files by a one-hot
+    select: the compiled program holds no scatter whose result has the
+    shape of either file, while the memory stores stay scatters."""
+    monkeypatch.setattr(importlib.import_module(
+        "repro.core.pipeline.execute"), "interpret_mode", lambda: False)
+    cfg = MachineConfig()
+    compiled = ex._run_positions.lower(
+        cfg, N_WARPS, *_positions_args(one_chip, one_chip, N_SM)).compile()
+    scattered = set(re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(",
+                               compiled.as_text()))
+    lanes = (N_SM, N_WARPS, isa.WARP_SIZE)
+    regs = "s32[%s]" % ",".join(map(str, lanes + (cfg.n_regs,)))
+    pred = "s32[%s]" % ",".join(map(str, lanes + (4,)))
+    assert f"s32[{N_SM},{G_WORDS + 1}]" in scattered   # global stores
+    assert regs not in scattered and pred not in scattered, scattered
 
 
 def test_sharded_run_positions_compiles(topo):
