@@ -6,7 +6,8 @@
 
 (a) The paper suite at the paper's size: autocorr, bitonic, matmul,
     reduction and transpose at n = 256 (matmul is a 16x16 grid), plus the
-    DSL-compiled histogram, scan and spmv at their largest serving sizes.
+    DSL-compiled histogram, scan, spmv and spmv_csr at their largest
+    serving sizes.
     Several tenants submit them to ``RuntimeServer(n_sm=2,
     resident_gmem=True)``, which drains them through submit -> policy ->
     executor.  Every ticket's memory must match the kernel's numpy oracle

@@ -17,7 +17,10 @@ Emission happens in three phases:
    branch emits the paper's SSY / guarded-BRA / ``.S`` warp-stack
    protocol with the reconvergence label on its join block; uniform
    branches are plain guarded BRAs like the hand-written kernels' loop
-   latches.
+   latches.  A divergent loop (per-lane trip counts) pushes its SSY
+   once, in the preheader, and its header's ``@LT BRA body`` falls
+   through into the ``.S`` exit, so the stack holds one entry per loop
+   whatever the trip count (:class:`repro.compiler.dsl._For`).
 
 The machine has no divide unit: ``udiv``/``umod`` that survive to
 emission (passes disabled, or a non-constant divisor) are emittable
@@ -193,6 +196,11 @@ def emit_function(fn: Function, n_regs: int = 16,
     labels = {b: f"{b.name}_{b.id}" for b in fn.blocks}
     sync_blocks = {t.reconv for b in fn.blocks
                    if isinstance((t := b.term), Branch) and t.reconv}
+    # a divergent loop pushes its exit once, before it is entered: its
+    # header's branch pushes no reconvergence entry of its own per trip
+    div_loops = [lp for lp in fn.loops if lp.divergent]
+    ssy_before = {lp.preheader: lp.exit for lp in div_loops}
+    pushed = {lp.header for lp in div_loops}
 
     def r(v: Value) -> str:
         try:
@@ -291,9 +299,11 @@ def emit_function(fn: Function, n_regs: int = 16,
         nxt = fn.blocks[bi + 1] if bi + 1 < len(fn.blocks) else None
         t = b.term
         if isinstance(t, Jump):
+            if b in ssy_before:
+                p.ssy(labels[ssy_before[b]])
             _emit_jump(p, t, gpr, labels, nxt)
         elif isinstance(t, Branch):
-            if t.reconv is not None:
+            if t.reconv is not None and b not in pushed:
                 p.ssy(labels[t.reconv])
             if t.t is nxt:
                 p.guard(pr(t.pred), ir.COND_COMPLEMENT[t.cond]) \

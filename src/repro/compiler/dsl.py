@@ -18,12 +18,16 @@ value is an int32 lane value; comparisons produce predicate values
 consumed by ``if_`` / ``select`` or materialized to 0/1 on demand.
 
 Divergence is tracked statically: a value is *uniform* when it provably
-does not depend on the thread index or on loaded data.  ``for_`` bounds
-must be uniform (the machine's warp stack reconverges structured ifs,
-not data-dependent loops); a non-uniform ``if_`` records its
-reconvergence block so codegen emits the paper's SSY / ``.S`` warp
-stack protocol, and ``syncthreads`` inside one is rejected at trace
-time — the hardware would deadlock the barrier.
+does not depend on the thread index or on loaded data.  A non-uniform
+``if_`` records its reconvergence block so codegen emits the paper's
+SSY / ``.S`` warp stack protocol.  A ``for_`` with a non-uniform start
+or stop is a *divergent loop* (each lane runs its own trip count, as a
+CSR row loop does): its exit is the reconvergence block, pushed once
+before the loop, and the lanes that leave at a trip wait there while
+the rest go round (see :class:`_For`).  Values and ``var`` cells
+written under either are non-uniform, and ``syncthreads`` inside
+either is rejected at trace time — the hardware would deadlock the
+barrier.
 """
 from __future__ import annotations
 
@@ -160,7 +164,10 @@ class Var:
     def get(self) -> Expr:
         self.k._flush_pending_else()
         v = self.k.fb.read_var(self.name)
-        return Expr(self.k, v, self._uniform)
+        # a value carried around an open divergent loop may be written
+        # later in its body, and the lanes leave it on different trips
+        carried = isinstance(v, ir.Param) and v.block in self.k._div_headers
+        return Expr(self.k, v, self._uniform and not carried)
 
     def set(self, value: IntLike) -> None:
         e = self.k._as_expr(value)
@@ -307,13 +314,26 @@ class _Else:
 
 
 class _For:
-    """``with k.for_(start, stop, step) as i:`` — a uniform counted loop.
+    """``with k.for_(start, stop, step) as i:`` — a counted loop.
 
     Lowers to preheader -> header(i, carried...) -> body ... latch ->
-    header, exit; the trip test is ``i < stop`` in the header.  Bounds
-    must be warp-uniform: the warp stack reconverges structured ifs,
-    not data-dependent loop exits, and a divergent backward branch
-    would let some lanes escape with divergence state still stacked.
+    header, exit; the trip test is ``i < stop`` in the header, and the
+    step must be warp-uniform and positive.
+
+    With a non-uniform ``start`` or ``stop`` the loop is *divergent*:
+    lanes leave on different trips.  It runs on the warp stack the
+    machine already has, with one entry per loop rather than one per
+    trip: the preheader pushes the exit as the reconvergence point once
+    (SSY), the header's ``@LT BRA body`` diverges when some lanes leave,
+    and the exit, ``.S``-labelled and placed right after the header on
+    the not-taken fall-through, pops the TAKEN entry of the lanes that
+    go on and sends them back into the body; when the last lanes leave,
+    the same ``.S`` pops the SSY entry and restores the mask from before
+    the loop.  The header and exit are laid out after the body, so the
+    latch falls through into the test.  A test that branched to the exit
+    instead, as a uniform loop's does, would make the leaving lanes the
+    taken side: one TAKEN entry would stay stacked per divergent trip,
+    and ``warp_stack_depth`` would overflow after about that many trips.
     """
     _counter = 0
 
@@ -327,12 +347,11 @@ class _For:
         k._flush_pending_else()
         fb = k.fb
         start, stop, step = (k._as_expr(b) for b in self.bounds)
-        for what, e in (("start", start), ("stop", stop), ("step", step)):
-            if not e.uniform:
-                raise CompileError(
-                    f"{fb.fn.name}: for_ {what} must be warp-uniform "
-                    "(loop trip counts cannot diverge on this machine); "
-                    "use if_ for per-thread conditions")
+        if not step.uniform:
+            raise CompileError(
+                f"{fb.fn.name}: for_ step must be warp-uniform (a "
+                "per-thread step would let lanes skip the trip test's "
+                "bound); put the per-thread part in start or stop")
         step_const = int(self.bounds[2]) \
             if isinstance(self.bounds[2], (int, bool)) \
             else ir.const_val(step.value)
@@ -342,6 +361,7 @@ class _For:
                 f"{step_const} — a zero step never terminates and "
                 "counting down is not supported (iterate up and index "
                 "with (stop - 1 - i))")
+        self.divergent = not (start.uniform and stop.uniform)
         _For._counter += 1
         self.ivar = f"$i{_For._counter}"
         self.preheader = fb.current
@@ -355,10 +375,14 @@ class _For:
         i = fb.read_var(self.ivar)          # creates the induction param
         cmp = k._emit(ir.ICMP, [i, stop.value], cond="LT")
         fb.terminate(ir.Branch(cmp, "LT", self.body, self.exit,
-                               reconv=None))
+                               reconv=self.exit if self.divergent
+                               else None))
         fb.current = self.body
         fb.seal(self.body)
-        return Expr(k, i, True)
+        if self.divergent:
+            k._divergence += 1
+            k._div_headers.add(self.header)
+        return Expr(k, i, not self.divergent)
 
     def __exit__(self, exc_type, *_):
         if exc_type is not None:
@@ -374,9 +398,18 @@ class _For:
         fb.seal(self.header)
         fb.seal(self.exit)
         fb.current = self.exit
+        if self.divergent:
+            k._divergence -= 1
+            k._div_headers.discard(self.header)
+            # header and exit after every body block: the latch falls
+            # through into the trip test, the test into the exit
+            for b in (self.header, self.exit):
+                fb.fn.blocks.remove(b)
+                fb.fn.blocks.append(b)
         fb.fn.loops.append(ir.LoopInfo(
             self.preheader, self.header, latch, self.exit,
-            self.start.value, self.stop.value, self.step.value))
+            self.start.value, self.stop.value, self.step.value,
+            divergent=self.divergent))
         return False
 
 
@@ -391,7 +424,8 @@ class Kernel:
         self.gridDim = _Dim3(self, isa.SR_NCTAX, isa.SR_NCTAY)
         self.gmem = _Mem(self, ir.LDG, ir.STG)
         self.smem = _Mem(self, ir.LDS, ir.STS)
-        self._divergence = 0              # nested non-uniform if_ depth
+        self._divergence = 0              # nested divergent if_/for_ depth
+        self._div_headers: set = set()    # headers of open divergent for_s
         self._pending_else: Optional[_If] = None
 
     # ------------------------------------------------------ trace helpers
@@ -480,8 +514,8 @@ class Kernel:
         if self._divergence > 0:
             raise CompileError(
                 f"{self.fb.fn.name}: syncthreads() inside a divergent "
-                "if_ would deadlock the barrier; hoist it out or make "
-                "the condition uniform")
+                "if_ or for_ would deadlock the barrier; hoist it out or "
+                "make the condition or the loop bounds uniform")
         self._emit(ir.BAR, [])
 
     def select(self, cond, a: IntLike, b: IntLike) -> Expr:

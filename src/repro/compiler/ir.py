@@ -243,12 +243,16 @@ class Block:
 
 
 class LoopInfo:
-    """Structured-loop metadata recorded by the tracer for the unroller."""
+    """Structured-loop metadata recorded by the tracer for the unroller
+    and, for a ``divergent`` loop (non-uniform bounds), for codegen: its
+    reconvergence entry is pushed in the preheader, not at the header's
+    branch."""
     __slots__ = ("preheader", "header", "latch", "exit", "start", "stop",
-                 "step")
+                 "step", "divergent")
 
     def __init__(self, preheader: Block, header: Block, latch: Block,
-                 exit: Block, start: Value, stop: Value, step: Value):
+                 exit: Block, start: Value, stop: Value, step: Value,
+                 divergent: bool = False):
         self.preheader = preheader
         self.header = header
         self.latch = latch
@@ -256,6 +260,7 @@ class LoopInfo:
         self.start = start
         self.stop = stop
         self.step = step
+        self.divergent = divergent
 
 
 class Function:

@@ -29,7 +29,8 @@ ALL = {
 
 
 def compiled_kernels():
-    """The DSL-compiled kernel modules (histogram, scan, spmv) — same
+    """The DSL-compiled kernel modules (histogram, scan, ELL and CSR
+    spmv) — same
     ``build/launch/make_gmem/oracle/out_slice/n_threads`` interface as
     the hand-written five, but authored in the ``repro.compiler`` front
     end and compiled at build() time.  Imported lazily so ``core`` has

@@ -5,8 +5,8 @@
         --params '{"n": 64}'
     PYTHONPATH=src python -m repro.launch.gpgpu_compile --all
 
-Compiles a DSL kernel — one of the bundled three (histogram, scan,
-spmv) or a ``.py`` file defining ``kernel(k, **params)`` (and
+Compiles a DSL kernel — one of the bundled four (histogram, scan,
+spmv, spmv_csr) or a ``.py`` file defining ``kernel(k, **params)`` (and
 optionally a ``PARAMS`` dict of defaults) — and prints the IR before
 and after the pass pipeline, the per-pass instruction counts, the
 final SASS-like listing, and the optimized-vs-naive emitted-

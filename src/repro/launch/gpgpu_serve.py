@@ -52,7 +52,8 @@ from repro.launch.compile_cache import enable_compile_cache
 #: the mixed workload exercises genuinely heterogeneous footprints)
 SIZES = {"autocorr": (32, 64, 128), "bitonic": (32, 64, 128),
          "matmul": (32, 64), "reduction": (32,), "transpose": (32, 64),
-         "histogram": (64, 128), "scan": (64, 128), "spmv": (32, 64)}
+         "histogram": (64, 128), "scan": (64, 128), "spmv": (32, 64),
+         "spmv_csr": (64, 128)}
 
 
 def workload_kernels(include_compiled: bool = True):

@@ -7,7 +7,8 @@
   ``dispatch`` → (``prepare``, ``device-execute`` per dispatch group,
   ``counter-sync``, ``to-results``) / ``complete``).  Spans carry
   attributes (tenant, ticket, bucket, n_blocks, predicted vs observed
-  cycles, loop ``trips`` / ``useful_steps`` / ``width``) settable
+  cycles, loop ``trips`` / ``useful_steps`` / ``width``, executed
+  ``winstr`` / ``lanes`` / ``stack_ops``) settable
   after entry via :meth:`Span.set`, and the finished tree is
   inspectable as ``tracer.roots`` for tests.  A server passes its own
   tracer down to the executor, so one tracer holds everything its

@@ -299,14 +299,18 @@ class DeviceGrid:
     rows are real blocks, and its ``device-execute`` span.  Padding is
     stripped on the host too, so a group costs the device nothing
     beyond its run; with ``tracer`` enabled each group's span gets
-    ``trips``, ``useful_steps`` and ``width``.
+    ``trips``, ``useful_steps`` and ``width`` (:meth:`loop_steps`) and
+    its real blocks' ``winstr``, ``lanes`` and ``stack_ops``
+    (:func:`_group_counts`), whose totals also go to the ``simt.*``
+    counters of ``metrics``.
     """
 
     def __init__(self, *, gmems, ctrs: Counters, split, sm_cyc, n_sm: int,
                  n_steps: int, launch_offsets: Sequence[int],
                  launch_blocks: Sequence[int], orig_lens: Sequence[int],
                  groups: Sequence[tuple],
-                 tracer: Optional[Tracer] = None):
+                 tracer: Optional[Tracer] = None,
+                 metrics: Optional[MetricsRegistry] = None):
         self._gmems = gmems              # (L_bucket, G) device
         self._ctrs = ctrs                # loop counters over positions
         self._split = split
@@ -315,6 +319,7 @@ class DeviceGrid:
         #: first row of each group in ``ctrs``, and the end
         self._starts = np.cumsum([0] + [len(v) for _, v, _ in groups])
         self._tracer = TRACER if tracer is None else tracer
+        self._metrics = METRICS if metrics is None else metrics
         self.n_sm = n_sm
         self.n_steps = n_steps
         self._offsets = list(launch_offsets)
@@ -356,14 +361,19 @@ class DeviceGrid:
                 c, sm_cyc = jax.device_get((self._ctrs, self._sm_cyc))
             c, trips = self._split(c)
             # strip the padding: counter row == global block position
-            rows = np.concatenate([start + keep for start, (keep, _, _)
-                                   in zip(self._starts, self._groups)])
+            per_group = [start + keep for start, (keep, _, _)
+                         in zip(self._starts, self._groups)]
+            rows = np.concatenate(per_group)
             self._host = (jax.tree.map(lambda x: x[rows], c), sm_cyc,
                           trips)
+            counts = _group_counts(c, per_group)
+            for k in ("winstr", "lanes", "stack_ops"):
+                self._metrics.counter("simt." + k).inc(
+                    sum(g[k] for g in counts))
             if self._tracer.enabled:
-                for (_, _, sp), steps in zip(self._groups,
-                                             self.loop_steps()):
-                    sp.set(**steps)
+                for (_, _, sp), steps, cnt in zip(
+                        self._groups, self.loop_steps(), counts):
+                    sp.set(**steps, **cnt)
         return self._host[:2]
 
     def loop_steps(self) -> List[dict]:
@@ -449,6 +459,21 @@ class DeviceGrid:
         return out
 
 
+def _group_counts(c: Counters, per_group: List[np.ndarray]) -> List[dict]:
+    """Per dispatch group, its real blocks' device counts, summed over
+    its rows of ``c``: ``winstr``, the warp-instructions they executed
+    (issues but the trip slot, split off already: a popped TAKEN entry
+    executes nothing); ``lanes``, the active lanes of those;
+    ``stack_ops``, their warp-stack pushes and pops.  ``lanes / (32 *
+    winstr)`` is the lane-level use that ``loop_steps``' block-level
+    counts cannot see."""
+    winstr = np.asarray(c.op_issues, np.int64).sum(1)
+    lanes = np.asarray(c.op_lanes, np.int64).sum(1)
+    stack = np.asarray(c.stack_ops, np.int64)
+    return [{"winstr": int(winstr[r].sum()), "lanes": int(lanes[r].sum()),
+             "stack_ops": int(stack[r].sum())} for r in per_group]
+
+
 def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
             cfg: MachineConfig = MachineConfig(), chunk: int = 8,
             pad_warps: Optional[int] = None,
@@ -470,10 +495,11 @@ def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
     falling back to it when only one device exists or ``n_sm`` does not
     divide over the devices.
 
-    Spans go to ``tracer`` and compile counts to ``metrics`` (default:
-    the process globals): ``prepare`` (binaries, memory and schedule
-    onto the device), then one ``device-execute`` per dispatch group
-    (its schedule arrays and its enqueue; ``jit_miss`` on a compile).
+    Spans go to ``tracer`` and compile and SIMT counts to ``metrics``
+    (default: the process globals): ``prepare`` (binaries, memory and
+    schedule onto the device), then one ``device-execute`` per dispatch
+    group (its schedule arrays and its enqueue; ``jit_miss`` on a
+    compile).
     """
     if not launches:
         raise ValueError("execute() needs at least one launch")
@@ -621,7 +647,8 @@ def execute(launches: Sequence[LaunchSpec], n_sm: int = 1,
                       split=functools.partial(split_trips, cfg, n_warps),
                       sm_cyc=sm_cyc, n_sm=n_sm, n_steps=-(-n_blocks // n_sm),
                       launch_offsets=offsets, launch_blocks=nblocks,
-                      orig_lens=orig_lens, groups=groups, tracer=tracer)
+                      orig_lens=orig_lens, groups=groups, tracer=tracer,
+                      metrics=metrics)
 
 
 def shard_plan(n_sm: int):

@@ -1,4 +1,4 @@
-"""The three DSL-compiled kernels (histogram, scan, ELL SpMV):
+"""The four DSL-compiled kernels (histogram, scan, ELL and CSR SpMV):
 bit-exact vs numpy oracles across grid/block sizes through run_grid,
 and differentially through the RuntimeServer under every drain policy,
 mixed with the legacy five benchmarks."""
@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 
 from repro import runtime as rt
-from repro.compiler.kernels import COMPILED, histogram
+from repro.compiler.kernels import COMPILED, histogram, spmv_csr
 from repro.core import scheduler
+from repro.core.machine import MachineConfig
 from repro.core.programs import ALL, compiled_kernels
 from repro.runtime import registry as reg
 
@@ -15,7 +16,7 @@ POLICY_NAMES = ("monolithic", "bucket", "fair", "balanced")
 
 #: sizes exercising 1, 2 and 4+ blocks where the kernel supports them
 SIZES = {"histogram": (32, 64, 128, 256), "scan": (32, 64, 128, 256),
-         "spmv": (32, 64, 128)}
+         "spmv": (32, 64, 128), "spmv_csr": (128, 256, 512)}
 
 
 def _seq(name, n, gseed=0):
@@ -121,11 +122,53 @@ def test_compiled_kernels_land_in_small_code_bucket():
 
 def test_programs_compiled_kernels_accessor():
     ck = compiled_kernels()
-    assert sorted(ck) == ["histogram", "scan", "spmv"]
+    assert sorted(ck) == ["histogram", "scan", "spmv", "spmv_csr"]
     for mod in ck.values():
         for attr in ("build", "launch", "make_gmem", "oracle",
                      "out_slice", "n_threads"):
             assert hasattr(mod, attr)
+
+
+def _csr_forced(n: int, rng) -> np.ndarray:
+    """spmv_csr's memory with an empty row, a row of 101 nonzeros and
+    one of up to 120 (in two warps of the first block), and the rest at
+    random."""
+    nnz = spmv_csr.nnz_of(n)
+    counts = np.zeros(n, np.int64)
+    counts[[3, 40]] = (101, min(120, nnz - 101))
+    rest = np.setdiff1d(np.arange(n), [0, 3, 40])
+    counts[rest] = np.bincount(rng.choice(rest, nnz - counts.sum()),
+                               minlength=n)[rest]
+    cols = np.concatenate([np.sort(rng.choice(n, c, replace=False))
+                           for c in counts])
+    row_ptr = np.concatenate([[0], np.cumsum(counts)])
+    return spmv_csr.pack(n, row_ptr, cols,
+                         rng.integers(-100, 100, nnz, dtype=np.int32),
+                         rng.integers(-100, 100, n, dtype=np.int32))
+
+
+@pytest.mark.parametrize("n", SIZES["spmv_csr"])
+def test_spmv_csr_through_server_with_forced_rows(n):
+    """CSR SpMV through the normal path (submit -> drain -> execute),
+    with an empty row and rows of 100+ nonzeros: bit-exact against the
+    numpy oracle, and in memory and every counter against the
+    sequential reference machine, with the warp stack at most two deep
+    however long a row is."""
+    rng = np.random.default_rng(n)
+    g0 = _csr_forced(n, rng)
+    code = spmv_csr.build(n)
+    srv = rt.RuntimeServer(n_sm=2, policy="bucket", resident_gmem=True)
+    fut = srv.submit_future(code, *spmv_csr.launch(n), g0.copy(),
+                            client="csr")
+    srv.drain()
+    got = fut.result()
+    np.testing.assert_array_equal(
+        np.asarray(got.gmem)[spmv_csr.out_slice(n)], spmv_csr.oracle(g0, n))
+    ref = scheduler.run_grid(code, *spmv_csr.launch(n), g0.copy(),
+                             MachineConfig(execute_backend="reference"))
+    _assert_bit_identical(got._replace(gmem=np.asarray(got.gmem)), ref)
+    assert got.stack_ops == ref.stack_ops and got.max_sp == ref.max_sp
+    assert got.max_sp <= 2 and not got.overflow
 
 
 # ------------------------------------------- server differential suite

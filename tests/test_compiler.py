@@ -60,8 +60,28 @@ def test_syncthreads_in_uniform_if_allowed():
 
 
 def test_for_with_divergent_bound_rejected():
-    def bad(k):
+    """A per-thread trip count is no longer rejected: it compiles to one
+    SSY in the preheader, before the header, and a ``.S`` exit right
+    after the header's test, and runs bit-exact.  What stays rejected
+    is a per-thread step."""
+    def div(k):
         with k.for_(0, k.tid) as i:      # per-thread trip count
+            k.gmem[32 + k.tid] = i
+    code = compile_kernel(div).code
+    ops = code[:, isa.F_OP]
+    ssy = np.flatnonzero(ops == isa.SSY)
+    assert len(ssy) == 1
+    target = int(code[ssy[0], isa.F_IMM])
+    assert code[target, isa.F_FLAGS] & isa.FLAG_SYNC     # the .S exit
+    test = target - 1                        # the header's @LT BRA body
+    assert ops[test] == isa.BRA and code[test, isa.F_FLAGS] & isa.FLAG_GUARD
+    assert ops[test - 1] == isa.ISETP and ssy[0] < test - 1
+    res = scheduler.run_grid(code, (1, 1), (32, 1), np.zeros(64, np.int32))
+    np.testing.assert_array_equal(res.gmem[32:],
+                                  np.maximum(np.arange(32) - 1, 0))
+
+    def bad(k):
+        with k.for_(0, 64, k.tid + 1) as i:
             k.gmem[i] = 0
     with pytest.raises(CompileError, match="warp-uniform"):
         dsl.trace(bad)

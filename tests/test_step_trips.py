@@ -124,6 +124,32 @@ def test_group_accounting_counts_padded_duplicate(shard_sm):
     assert w.counter_syncs == 1
 
 
+@pytest.mark.parametrize("shard_sm", [False, True])
+def test_group_counts_are_the_launch_counters(shard_sm):
+    """A group's ``winstr`` / ``lanes`` / ``stack_ops`` are its real
+    blocks' counters from the same fetch: over the groups they sum to
+    the launches' executed issues, active lanes and stack operations
+    (padded duplicates left out), and reach the group spans and the
+    ``simt.*`` counters.  CSR SpMV's divergent row loop makes stack
+    traffic and idle lanes."""
+    from repro.compiler.kernels import spmv_csr
+    n = 256
+    csr = (spmv_csr.build(n), *spmv_csr.launch(n),
+           spmv_csr.make_gmem(np.random.default_rng(1), n))
+    specs = [rt.LaunchSpec(*_program("bitonic")), rt.LaunchSpec(*csr)]
+    tracer, m = obs.Tracer().start(), obs.MetricsRegistry()
+    res = ex.execute(specs, n_sm=2, chunk=2, shard_sm=shard_sm,
+                     tracer=tracer, metrics=m).to_results()
+    want = {"winstr": sum(int(r.op_issues.sum()) for r in res),
+            "lanes": sum(int(r.op_lanes.sum()) for r in res),
+            "stack_ops": sum(r.stack_ops for r in res)}
+    assert want["stack_ops"] > 0 and want["lanes"] < 32 * want["winstr"]
+    assert {k: m.counter("simt." + k).value for k in want} == want
+    groups = tracer.find("device-execute")
+    assert len(groups) == 2
+    assert {k: sum(g.attrs[k] for g in groups) for k in want} == want
+
+
 def test_server_tracer_sees_the_executor_under_dispatch():
     code, grid, bd, g0 = _program("bitonic")
     tracer, m = obs.Tracer(), obs.MetricsRegistry()
