@@ -95,14 +95,81 @@ def run_block_body(cfg: MachineConfig, n_warps: int, code, block_dim,
     :data:`TRIP_SLOT` entry; :func:`split_trips` takes the loop's trip
     count out of them, and every caller does so before a ``Counters``
     leaves it.
+
+    Under ``jax.vmap`` — a dispatch group's blocks — the blocks run in
+    one loop of their own (:func:`_group_loop`), not in a batched
+    per-block loop.
     """
+    return _machine_loop(cfg, n_warps)(
+        code, jnp.asarray(block_dim, jnp.int32), block_dim_xy, block_xy,
+        grid_xy, gmem)
+
+
+def _machine_loop(cfg: MachineConfig, n_warps: int):
+    """:func:`run_block_body`'s loop for one ``(cfg, n_warps)``: a plain
+    per-block loop, batched by :func:`_group_loop`."""
+    @jax.custom_batching.custom_vmap
+    def loop(code, block_dim, block_dim_xy, block_xy, grid_xy, gmem):
+        lut = jnp.asarray(isa.COND_LUT)
+        body = functools.partial(step_fn(cfg), cfg, code, lut, block_dim_xy,
+                                 block_xy, grid_xy)
+        st = jax.lax.while_loop(functools.partial(block_running, cfg), body,
+                                init_state(cfg, n_warps, block_dim, gmem))
+        return st.gmem[:-1], st.gw[:-1], st.counters
+
+    @loop.def_vmap
+    def group(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+                for a, b in zip(args, in_batched)]
+        st = _group_loop(cfg, n_warps, *args)
+        out = st.gmem[:, :-1], st.gw[:, :-1], st.counters
+        return out, jax.tree.map(lambda _: True, out)
+
+    return loop
+
+
+#: The leaves a step rewrites by scatter.  The group loop takes them
+#: from the step as they come: a select between a block's old and new
+#: memory would keep the old row live, and the scatter would then copy
+#: the whole row every trip instead of writing in place.
+_MEMORY = ("smem", "gmem", "gw")
+
+
+def _group_loop(cfg: MachineConfig, n_warps: int, code, block_dim,
+                block_dim_xy, block_xy, grid_xy, gmem) -> SMState:
+    """A dispatch group's blocks (leading axis) in one machine loop.
+
+    The loop runs while any block runs (:func:`block_running`, per
+    block) and steps every block each trip.  A stopped block is stepped
+    parked: every warp FINISHED and no thread alive or active, so it
+    issues nothing and each of its stores rewrites a sentinel word with
+    that word's own value — its memory comes out of the step unchanged.
+    Its other leaves, counters included, are restored by a select, so
+    its trips, cycles and counts stop where the per-block loop stops
+    them, the runaway guard included."""
     lut = jnp.asarray(isa.COND_LUT)
-    st0 = init_state(cfg, n_warps, block_dim, gmem)
-    body = functools.partial(step_fn(cfg), cfg, code, lut, block_dim_xy,
-                             block_xy, grid_xy)
-    st = jax.lax.while_loop(functools.partial(block_running, cfg), body,
-                            st0)
-    return st.gmem[:-1], st.gw[:-1], st.counters
+    step = jax.vmap(functools.partial(step_fn(cfg), cfg),
+                    in_axes=(0, None, 0, 0, 0, 0))
+    running = jax.vmap(functools.partial(block_running, cfg))
+
+    def body(st):
+        go = running(st)
+        parked = st._replace(
+            wstate=jnp.where(go[:, None], st.wstate, FINISHED),
+            alive=st.alive & go[:, None, None],
+            active=st.active & go[:, None, None])
+        new = step(code, lut, block_dim_xy, block_xy, grid_xy, parked)
+
+        def keep(a, b):
+            return jnp.where(go.reshape(go.shape + (1,) * (a.ndim - 1)),
+                             a, b)
+        return new._replace(**{
+            f: jax.tree.map(keep, getattr(new, f), getattr(st, f))
+            for f in SMState._fields if f not in _MEMORY})
+
+    st0 = jax.vmap(functools.partial(init_state, cfg, n_warps))(block_dim,
+                                                                gmem)
+    return jax.lax.while_loop(lambda st: jnp.any(running(st)), body, st0)
 
 
 def split_trips(cfg: MachineConfig, n_warps: int, ctr: Counters):

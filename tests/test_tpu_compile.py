@@ -121,6 +121,32 @@ def test_run_positions_register_files_not_scattered(one_chip, monkeypatch):
     assert regs not in scattered and pred not in scattered, scattered
 
 
+#: CSR SpMV at n = 8,192, the largest tenant memory a cell serves: a
+#: 2,097,152-word bucket, 4 warps, the 64-row code bucket
+SPMV_G_WORDS, SPMV_CODE_ROWS, SPMV_N_WARPS = 2097152, 64, 4
+
+
+def test_run_positions_memory_row_not_copied(one_chip, monkeypatch):
+    """A dispatch group's blocks run in one machine loop that takes the
+    memories from the step unselected: at the largest memory bucket the
+    compiled program holds no copy and no select of the group's memory
+    row or written mask, and the global and written-mask stores stay
+    scatters of that shape (in place)."""
+    monkeypatch.setattr(importlib.import_module(
+        "repro.core.pipeline.execute"), "interpret_mode", lambda: False)
+    args = list(_positions_args(one_chip, one_chip, N_SM))
+    args[0] = _shape((1, SPMV_CODE_ROWS, isa.NUM_FIELDS), one_chip)
+    args[8] = _shape((1, SPMV_G_WORDS), one_chip)
+    text = ex._run_positions.lower(MachineConfig(), SPMV_N_WARPS,
+                                   *args).compile().as_text()
+    ops = set(re.findall(r"= (\w+\[[\d,]*\])\S* (copy|select|scatter)\(",
+                         text))
+    for row in (f"s32[{N_SM},{SPMV_G_WORDS + 1}]",
+                f"pred[{N_SM},{SPMV_G_WORDS + 1}]"):
+        assert (row, "scatter") in ops, row
+        assert (row, "copy") not in ops and (row, "select") not in ops, row
+
+
 def test_sharded_run_positions_compiles(topo):
     """The ``shard_sm`` dispatch over a mesh of the four described chips:
     one SM per chip, gmem merged across chips by collectives."""
