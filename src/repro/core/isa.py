@@ -184,8 +184,8 @@ IS_SMEM[list(SMEM_OPS)] = True
 def _table_mask(table: np.ndarray) -> int:
     """Fold a <=31-entry bool opcode table into a scalar int bitmask, so
     pipeline stages can test membership with ``(mask >> op) & 1`` — a
-    scalar constant, usable inside Pallas kernel bodies where captured
-    array constants are rejected (NUM_OPCODES=28 fits int32)."""
+    scalar constant, not an array table gather (NUM_OPCODES=28 fits
+    int32)."""
     return int(sum(1 << i for i, v in enumerate(table) if v))
 
 

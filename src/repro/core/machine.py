@@ -28,24 +28,19 @@ property that motivates the paper.
 
 Issue disciplines (``MachineConfig.execute_backend``):
 
-* ``"jnp"`` / ``"pallas"`` — lockstep all-warp issue: every READY warp
-  fetches, decodes and executes its instruction in the same
-  ``lax.while_loop`` iteration over a (W, 32) lane grid, with the
-  execute stage running either as pure jnp or as the Pallas ``simt_alu``
-  VPU kernel.  Cycle counters still charge the seed's serialized-issue
-  cost, so all paper timing results are unchanged.
-* ``"pallas_fused"`` — same discipline, but the whole
-  fetch/read/execute/write/control step runs as ONE Pallas kernel per
-  ``while_loop`` iteration (``pipeline/fused.py``), reusing the stage
-  functions so results stay bit-exact.
+* ``"jnp"`` — lockstep all-warp issue: every READY warp fetches,
+  decodes and executes its instruction in the same ``lax.while_loop``
+  iteration over a (W, 32) lane grid.  Cycle counters still charge the
+  seed's serialized-issue cost, so all paper timing results are
+  unchanged.
 * ``"reference"`` — the seed interpreter: one round-robin warp per
-  iteration; the bit-exact oracle for the vectorized paths.
+  iteration; the bit-exact oracle for the lockstep path.
 """
 from __future__ import annotations
 
 from .pipeline import (  # noqa: F401  (re-exported public surface)
     EXECUTE_BACKENDS, FINISHED, READY, WAIT, Counters, MachineConfig,
-    SMState, _BITS, _LANES, _pack, _run_block_jit, _unpack, init_state,
+    SMState, _LANES, _pack, _run_block_jit, _unpack, init_state,
     issue_one_warp, run_block, sm_step)
 
 # Back-compat alias for the seed's private initializer name.

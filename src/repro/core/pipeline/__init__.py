@@ -6,15 +6,10 @@ control unit — is one module per stage:
 * :mod:`fetch_decode` — barrier release, all-warp instruction fetch,
   field decode, ``.S`` reconvergence pop;
 * :mod:`read`         — operand units, guard LUT, S2R, memory read ports;
-* :mod:`execute`      — the pluggable SP-array backend (pure jnp or the
-  Pallas ``simt_alu`` VPU kernel);
+* :mod:`execute`      — the SP array's ALU;
 * :mod:`write`        — register/predicate writeback, global/shared
   stores;
 * :mod:`control`      — warp stack, EXIT/BAR, next PC, counters;
-* :mod:`fused`        — the whole step as ONE Pallas kernel
-  (``execute_backend="pallas_fused"``): same stage functions traced
-  inside a single ``pallas_call`` so no intermediate (W, 32) arrays are
-  materialized between stages;
 * :mod:`reference`    — the seed one-warp-per-issue interpreter, kept as
   the equivalence oracle (``execute_backend="reference"``).
 
@@ -35,22 +30,21 @@ import jax.numpy as jnp
 from ...obs import jit_call
 from .. import isa
 from .state import (EXECUTE_BACKENDS, FINISHED, READY, TRIP_SLOT, WAIT,
-                    Counters, MachineConfig, SMState, _BITS, _LANES, _pack,
+                    Counters, MachineConfig, SMState, _LANES, _pack,
                     _unpack, init_state)
 from .fetch_decode import Decoded, fetch_decode
 from .read import Operands, read_operands
-from .execute import EXECUTE_STAGE_BACKENDS, execute, interpret_mode
+from .execute import execute
 from .write import write_back
 from .control import control
-from .fused import fused_sm_step
 from .reference import issue_one_warp
 
 __all__ = [
-    "EXECUTE_BACKENDS", "EXECUTE_STAGE_BACKENDS", "READY", "WAIT",
-    "FINISHED", "Counters", "Decoded", "MachineConfig", "Operands",
-    "SMState", "TRIP_SLOT", "sm_step", "fused_sm_step", "issue_one_warp",
+    "EXECUTE_BACKENDS", "READY", "WAIT", "FINISHED", "Counters",
+    "Decoded", "MachineConfig", "Operands", "SMState", "TRIP_SLOT",
+    "sm_step", "issue_one_warp",
     "init_state", "run_block", "run_block_body", "split_trips", "step_fn",
-    "block_running", "_run_block_jit", "_BITS", "_LANES", "_pack",
+    "block_running", "_run_block_jit", "_LANES", "_pack",
     "_unpack",
 ]
 
@@ -177,8 +171,8 @@ def split_trips(cfg: MachineConfig, n_warps: int, ctr: Counters):
     not, on the device or on the host: the opcode vectors without
     :data:`TRIP_SLOT`, and how many times the loop ran its step.  The
     trips stay out of ``Counters`` because they depend on the issue
-    discipline (``reference`` visits one warp a step, the lockstep
-    backends all ``n_warps``)."""
+    discipline (``reference`` visits one warp a step, the lockstep step
+    all ``n_warps``)."""
     warps_per_step = 1 if cfg.execute_backend == "reference" else n_warps
     return (ctr._replace(op_issues=ctr.op_issues[..., :TRIP_SLOT],
                          op_lanes=ctr.op_lanes[..., :TRIP_SLOT]),
@@ -188,11 +182,7 @@ def split_trips(cfg: MachineConfig, n_warps: int, ctr: Counters):
 def step_fn(cfg: MachineConfig):
     """The step function of ``cfg.execute_backend``, called as
     ``step(cfg, code, lut, block_dim_xy, block_xy, grid_xy, st)``."""
-    if cfg.execute_backend == "reference":
-        return issue_one_warp
-    if cfg.execute_backend == "pallas_fused":
-        return functools.partial(fused_sm_step, interpret=interpret_mode())
-    return sm_step
+    return issue_one_warp if cfg.execute_backend == "reference" else sm_step
 
 
 def block_running(cfg: MachineConfig, st: SMState):

@@ -87,9 +87,7 @@ def control(cfg: MachineConfig, st: SMState, dec: Decoded, ops: Operands):
                        jnp.where(is_bar, WAIT, dec.wstate))
 
     # ---- counters / cycle cost -------------------------------------------
-    # scalar opcode bitmasks, not array table gathers: this stage is
-    # also traced inside the fused Pallas kernel (fused.py), which
-    # rejects captured array constants
+    # scalar opcode bitmasks, not array table gathers
     is_gmem = ((jnp.int32(isa.IS_GMEM_MASK) >> dec.op) & 1) != 0
     is_smem = ((jnp.int32(isa.IS_SMEM_MASK) >> dec.op) & 1) != 0
     cost = jnp.where(

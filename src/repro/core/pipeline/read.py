@@ -38,10 +38,7 @@ class Operands(NamedTuple):
 
 def _read_col(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """table (W, 32, R), idx (W,) -> (W, 32) column ``idx[w]`` per warp;
-    an index outside the file reads 0.
-
-    The column iota is built at trace time (Pallas kernel bodies reject
-    captured array constants — fused.py traces this stage in-kernel)."""
+    an index outside the file reads 0."""
     cols = jnp.arange(table.shape[2], dtype=jnp.int32)
     hit = cols == idx[:, None, None]
     return jnp.sum(jnp.where(hit, table, 0), axis=2, dtype=table.dtype)
@@ -71,8 +68,6 @@ def read_operands(cfg: MachineConfig, lut: jnp.ndarray,
         else jnp.zeros_like(s1)
 
     # ---- special-register values for S2R -------------------------------
-    # lane iota built at trace time (Pallas kernel bodies reject
-    # captured array constants — fused.py traces this stage in-kernel)
     lanes = jnp.arange(isa.WARP_SIZE, dtype=jnp.int32)
     tid_flat = arange_w[:, None] * 32 + lanes[None, :]   # (W, 32)
     bdx, bdy = block_dim_xy[0], block_dim_xy[1]

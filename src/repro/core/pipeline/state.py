@@ -19,18 +19,12 @@ from .. import isa
 READY, WAIT, FINISHED = 0, 1, 2
 
 _LANES = jnp.arange(isa.WARP_SIZE, dtype=jnp.int32)
-_BITS = jnp.uint32(1) << jnp.arange(isa.WARP_SIZE, dtype=jnp.uint32)
 
-#: Execute-stage backends selectable via ``MachineConfig.execute_backend``:
-#:   ``"jnp"``          — all-warp pipeline, pure-jnp vector ALU (default);
-#:   ``"pallas"``       — all-warp pipeline, Pallas ``simt_alu`` VPU kernel
-#:                        for the execute stage only;
-#:   ``"pallas_fused"`` — the whole pipeline step (fetch/read/execute/
-#:                        write/control) as ONE Pallas kernel
-#:                        (:mod:`repro.core.pipeline.fused`);
-#:   ``"reference"``    — the seed one-warp-per-issue interpreter, kept
-#:                        as the equivalence oracle for the vector paths.
-EXECUTE_BACKENDS = ("jnp", "pallas", "pallas_fused", "reference")
+#: Issue disciplines selectable via ``MachineConfig.execute_backend``:
+#:   ``"jnp"``       — the lockstep all-warp pipeline (default);
+#:   ``"reference"`` — the seed one-warp-per-issue interpreter, kept as
+#:                     the equivalence oracle for the lockstep step.
+EXECUTE_BACKENDS = ("jnp", "reference")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,13 +115,7 @@ class SMState(NamedTuple):
 
 
 def _pack(mask_bool: jnp.ndarray) -> jnp.ndarray:
-    """(..., 32) bool lane mask -> (...,) uint32 bitmask.
-
-    The bit-position vector is rebuilt at trace time (iota) instead of
-    referencing the module-level ``_BITS`` constant so this helper can
-    also be traced inside a Pallas kernel body, where captured array
-    constants are rejected (see :mod:`repro.core.pipeline.fused`).
-    """
+    """(..., 32) bool lane mask -> (...,) uint32 bitmask."""
     bits = jnp.uint32(1) << jnp.arange(isa.WARP_SIZE, dtype=jnp.uint32)
     return jnp.sum(jnp.where(mask_bool, bits, jnp.uint32(0)), axis=-1)
 

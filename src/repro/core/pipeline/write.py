@@ -37,11 +37,7 @@ def _write_col(table: jnp.ndarray, idx: jnp.ndarray, wr: jnp.ndarray,
                val: jnp.ndarray) -> jnp.ndarray:
     """table (W, 32, R), idx (W,), wr / val (W, 32) -> ``table`` with
     ``val`` in column ``idx[w]`` of every lane where ``wr``; an index
-    outside the file writes nothing.
-
-    The column iota is built at trace time: this stage is also traced
-    inside the fused Pallas kernel, which rejects captured array
-    constants (fused.py)."""
+    outside the file writes nothing."""
     cols = jnp.arange(table.shape[2], dtype=jnp.int32)
     hit = wr[..., None] & (cols == idx[:, None, None])
     return jnp.where(hit, val[..., None], table)

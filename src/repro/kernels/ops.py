@@ -12,18 +12,9 @@ import jax.numpy as jnp
 
 from . import flash_attention as _fa
 from . import matmul as _mm
-from . import simt_alu as _sa
 from . import ref
 
 INTERPRET = jax.default_backend() != "tpu"
-
-
-def simt_alu(op, s1, s2, s3, cond, s2r, mask, *, enable_mul=True,
-             num_read_operands=3):
-    return _sa.simt_alu(op, s1, s2, s3, cond, s2r, mask,
-                        enable_mul=enable_mul,
-                        num_read_operands=num_read_operands,
-                        interpret=INTERPRET)
 
 
 def matmul(a, b, **kw):

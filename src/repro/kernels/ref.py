@@ -1,42 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles of the LM stack's Pallas kernels (allclose targets)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro.core import isa
-
-
-def simt_alu_ref(op, s1, s2, s3, cond, s2r, mask, *,
-                 enable_mul: bool = True, num_read_operands: int = 3):
-    """Oracle for kernels.simt_alu: same semantics, plain jnp."""
-    opb = op[:, None]
-    condb = cond != 0
-    sh = s2 & 31
-    u1 = s1.astype(jnp.uint32)
-    mul = (s1 * s2) if enable_mul else jnp.zeros_like(s1)
-    mad = (s1 * s2 + s3) if (enable_mul and num_read_operands >= 3) \
-        else jnp.zeros_like(s1)
-    res = jnp.select(
-        [opb == o for o in (isa.MOV, isa.IADD, isa.ISUB, isa.IMUL,
-                            isa.IMAD, isa.IMIN, isa.IMAX, isa.IABS,
-                            isa.AND, isa.OR, isa.XOR, isa.NOT, isa.SHL,
-                            isa.SHR, isa.SAR, isa.ISET, isa.SELP,
-                            isa.S2R)],
-        [s2, s1 + s2, s1 - s2, mul, mad, jnp.minimum(s1, s2),
-         jnp.maximum(s1, s2), jnp.abs(s1), s1 & s2, s1 | s2, s1 ^ s2,
-         ~s1, (u1 << sh.astype(jnp.uint32)).astype(jnp.int32),
-         (u1 >> sh.astype(jnp.uint32)).astype(jnp.int32), s1 >> sh,
-         condb.astype(jnp.int32), jnp.where(condb, s1, s2), s2r],
-        jnp.zeros_like(s1))
-    d = s1 - s2
-    nib = ((d < 0).astype(jnp.int32)
-           | ((d == 0).astype(jnp.int32) << 1)
-           | ((u1 < s2.astype(jnp.uint32)).astype(jnp.int32) << 2)
-           | ((((s1 ^ s2) & (s1 ^ d)) < 0).astype(jnp.int32) << 3))
-    m = mask != 0
-    return (jnp.where(m, res, 0),
-            jnp.where(m & (opb == isa.ISETP), nib, 0))
 
 
 def matmul_ref(a, b):

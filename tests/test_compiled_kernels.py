@@ -51,20 +51,22 @@ def test_compiled_kernel_matches_oracle_across_sizes(name):
 
 
 @pytest.mark.parametrize("name", sorted(COMPILED))
-def test_compiled_kernel_fused_backend_bit_exact(name):
-    """The single-kernel ``pallas_fused`` step path reproduces the jnp
-    backend bit-for-bit on every DSL-compiled kernel (gmem, per-block
-    cycles, per-opcode issue/lane counters)."""
-    from repro.core.machine import MachineConfig
+def test_compiled_kernel_reference_bit_exact(name):
+    """The lockstep ``jnp`` step reproduces the one-warp-per-issue
+    ``reference`` interpreter bit-for-bit on every DSL-compiled kernel
+    through ``run_grid`` (gmem, per-block cycles, per-opcode issue/lane
+    counters)."""
     mod = COMPILED[name]
     n = SIZES[name][1]
     code = mod.build(n)
     g0 = mod.make_gmem(np.random.default_rng(5), n)
     res = {}
-    for be in ("jnp", "pallas_fused"):
+    for be in ("jnp", "reference"):
         cfg = MachineConfig(execute_backend=be)
         res[be] = scheduler.run_grid(code, *mod.launch(n), g0.copy(), cfg)
-    _assert_bit_identical(res["pallas_fused"], res["jnp"])
+    _assert_bit_identical(res["jnp"], res["reference"])
+    assert res["jnp"].stack_ops == res["reference"].stack_ops
+    assert res["jnp"].max_sp == res["reference"].max_sp
 
 
 @pytest.mark.parametrize("name", sorted(COMPILED))

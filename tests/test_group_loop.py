@@ -2,9 +2,9 @@
 
 * Under ``jax.vmap`` each block of a group ends exactly as its own
   unbatched machine loop leaves it — memory, written mask, every
-  counter and the trip slot — on every step backend, when the blocks
-  stop at very different trips and when the runaway guard stops one of
-  them beside a block that runs to EXIT.
+  counter and the trip slot — under both issue disciplines, when the
+  blocks stop at very different trips and when the runaway guard stops
+  one of them beside a block that runs to EXIT.
 * What that rests on: stepping a block whose warps are all FINISHED,
   with no thread alive or active, leaves its memories bit-identical,
   sentinel words included.

@@ -1,89 +1,12 @@
-"""Pallas kernels vs pure-jnp oracles, swept over shapes/dtypes
-(interpret mode: the kernel body executes on CPU)."""
-import jax
+"""The LM stack's Pallas kernels vs pure-jnp oracles, swept over
+shapes/dtypes (interpret mode: the kernel body executes on CPU)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis",
-                    reason="property tests need the hypothesis extra")
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import isa
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matmul import matmul
-from repro.kernels.simt_alu import simt_alu
-
-
-# ------------------------------------------------------------- simt_alu
-def _alu_inputs(rng, W, L):
-    s1 = rng.integers(-2**31, 2**31 - 1, (W, L)).astype(np.int32)
-    s2 = rng.integers(-2**31, 2**31 - 1, (W, L)).astype(np.int32)
-    s3 = rng.integers(-999, 999, (W, L)).astype(np.int32)
-    cond = (rng.random((W, L)) > 0.5).astype(np.int32)
-    s2r = rng.integers(0, 1024, (W, L)).astype(np.int32)
-    mask = (rng.random((W, L)) > 0.25).astype(np.int32)
-    return s1, s2, s3, cond, s2r, mask
-
-
-@pytest.mark.parametrize("opc", [isa.MOV, isa.IADD, isa.ISUB, isa.IMUL,
-                                 isa.IMAD, isa.IMIN, isa.IMAX, isa.IABS,
-                                 isa.AND, isa.OR, isa.XOR, isa.NOT,
-                                 isa.SHL, isa.SHR, isa.SAR, isa.ISETP,
-                                 isa.ISET, isa.SELP, isa.S2R])
-def test_simt_alu_opcodes(opc, rng):
-    W, L = 9, 32
-    op = np.full(W, opc, np.int32)
-    args = _alu_inputs(rng, W, L)
-    out, nib = simt_alu(op, *args, interpret=True)
-    eout, enib = ref.simt_alu_ref(jnp.asarray(op),
-                                  *(jnp.asarray(x) for x in args))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(eout))
-    np.testing.assert_array_equal(np.asarray(nib), np.asarray(enib))
-
-
-def test_simt_alu_mul_removed(rng):
-    W, L = 4, 32
-    op = np.full(W, isa.IMUL, np.int32)
-    z = np.zeros((W, L), np.int32)
-    s1 = rng.integers(-99, 99, (W, L)).astype(np.int32)
-    out, _ = simt_alu(op, s1, s1, z, z, z, np.ones((W, L), np.int32),
-                      enable_mul=False, interpret=True)
-    assert (np.asarray(out) == 0).all()  # multiplier absent
-
-
-def test_simt_alu_third_port_removed(rng):
-    """§4.2: without the third read port, IMAD's addend contributes
-    nothing — the whole mad datapath is absent."""
-    W, L = 4, 32
-    op = np.full(W, isa.IMAD, np.int32)
-    s1 = rng.integers(-99, 99, (W, L)).astype(np.int32)
-    s2 = rng.integers(-99, 99, (W, L)).astype(np.int32)
-    s3 = rng.integers(1, 99, (W, L)).astype(np.int32)
-    z = np.zeros((W, L), np.int32)
-    ones = np.ones((W, L), np.int32)
-    out, _ = simt_alu(op, s1, s2, s3, z, z, ones,
-                      num_read_operands=2, interpret=True)
-    assert (np.asarray(out) == 0).all()
-    out3, _ = simt_alu(op, s1, s2, s3, z, z, ones,
-                       num_read_operands=3, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out3), s1 * s2 + s3)
-
-
-@given(st.integers(1, 40), st.integers(1, 32), st.integers(0, 2**31 - 1))
-@settings(max_examples=10, deadline=None)
-def test_simt_alu_shape_sweep(W, L, seed):
-    rng = np.random.default_rng(seed)
-    op = rng.choice([isa.IADD, isa.XOR, isa.SHL], W).astype(np.int32)
-    s1 = rng.integers(-100, 100, (W, L)).astype(np.int32)
-    s2 = rng.integers(-100, 100, (W, L)).astype(np.int32)
-    z = np.zeros((W, L), np.int32)
-    mask = np.ones((W, L), np.int32)
-    out, _ = simt_alu(op, s1, s2, z, z, z, mask, interpret=True)
-    eout, _ = ref.simt_alu_ref(*(jnp.asarray(x) for x in
-                                 (op, s1, s2, z, z, z, mask)))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(eout))
 
 
 # --------------------------------------------------------------- matmul

@@ -5,19 +5,12 @@ paper's benchmarks can express — including divergent control flow and
 barrier-heavy block cooperation — the final global memory, the written
 mask, and every activity counter (per-opcode issues/lanes, cycles,
 stack operations) are bit-identical to the seed one-warp-per-issue
-interpreter kept as ``execute_backend="reference"``.  Both vectorized
-execute backends (pure jnp and the Pallas ``simt_alu`` kernel in
-interpret mode) are held to the same property.
+interpreter kept as ``execute_backend="reference"``.
 
 A seeded random-program sweep (mirroring the hypothesis strategies in
 test_machine.py, but deterministic so it runs without the optional
 dependency) additionally pins both issue disciplines to the pure-numpy
 ``RefMachine`` oracle.
-
-``pallas_fused`` — the single-kernel fast path that runs the whole
-fetch/read/execute/write/control step inside one Pallas kernel — is
-swept alongside the per-stage backends and held to the identical
-bit-exactness bar.
 """
 import numpy as np
 import pytest
@@ -27,7 +20,7 @@ from repro.core.machine import MachineConfig
 from repro.core.microblaze import RefMachine
 from repro.core.programs import ALL
 
-VEC_BACKENDS = ("jnp", "pallas", "pallas_fused")
+VEC_BACKENDS = ("jnp",)
 
 # divergent and barrier-heavy architectural variants (§4 axes)
 CONFIGS = {
@@ -94,11 +87,11 @@ def test_paper_program_grid_equivalence(name, rng):
     g0 = mod.make_gmem(rng, n)
     grid, bd = mod.launch(n)
     res = {}
-    for be in ("reference", "jnp", "pallas_fused"):
+    for be in ("reference",) + VEC_BACKENDS:
         cfg = MachineConfig(execute_backend=be)
         res[be] = scheduler.run_grid(code, grid, bd, g0.copy(), cfg)
     ref = res["reference"]
-    for be in ("jnp", "pallas_fused"):
+    for be in VEC_BACKENDS:
         vec = res[be]
         np.testing.assert_array_equal(ref.gmem, vec.gmem, err_msg=be)
         np.testing.assert_array_equal(ref.cycles_per_block,
