@@ -76,18 +76,24 @@ class MachineConfig:
 #: Slot past the opcodes in the loop's ``op_issues`` / ``op_lanes``
 #: vectors.  Every warp a step visits adds one issue: to its opcode if
 #: it executes, else to ``TRIP_SLOT`` (with no lanes).  So a block's
-#: issues over all slots are its loop trips times the warps per step,
+#: issues up to this slot are its loop trips times the warps per step,
 #: counted by the scatter that already runs every step, with no op of
 #: its own.  ``split_trips`` strips the slot, so a block's
 #: :class:`Counters` hold opcodes only.
 TRIP_SLOT = isa.NUM_OPCODES
+
+#: Slots the machine loop appends to ``op_issues`` past
+#: :data:`TRIP_SLOT` when it ends: the trips on which its store phase ran
+#: the global and the shared scatter.  ``split_trips`` strips them too.
+GSTORE_SLOT, SSTORE_SLOT = TRIP_SLOT + 1, TRIP_SLOT + 2
 
 
 class Counters(NamedTuple):
     """Per-block dynamic-activity counters (drive the energy model).
 
     Inside the machine loop both opcode vectors carry one more entry,
-    :data:`TRIP_SLOT`."""
+    :data:`TRIP_SLOT`; the loop's ``op_issues`` comes out with two more,
+    :data:`GSTORE_SLOT` and :data:`SSTORE_SLOT`."""
     op_issues: jnp.ndarray   # (NUM_OPCODES,) instruction issues per opcode
     op_lanes: jnp.ndarray    # (NUM_OPCODES,) active-lane executions per opcode
     cycles: jnp.ndarray      # SM cycles for this block

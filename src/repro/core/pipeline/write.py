@@ -6,13 +6,19 @@ axis: each (warp, lane) writes at most one column of its own row
 (``dst`` / ``pdst`` per warp), so the write is one dense ``where`` over
 the (W, 32, R) file, with no scatter.  A TPU scatter pays for every
 update, the select only for the file's words, which at these sizes is
-the cheaper of the two at every warp count.  Global and shared stores
-from all warps flatten to one scatter each, with inactive lanes
-redirected to the sentinel word (they rewrite its current value, so the
-scatter needs no branch).  Cross-warp stores to the same address in one
-step have an implementation-defined winner (XLA scatter with duplicate
-indices) — the CUDA-race semantics the paper's race-free programs never
-observe; CUDA gives no stronger guarantee either.
+the cheaper of the two at every warp count.
+
+Global and shared stores are not written here: :func:`write_back`
+returns them as one :class:`Store` request a port, and the machine
+loop's store phase applies each with :func:`store_global` /
+:func:`store_shared` — one scatter a port, run only on a trip where
+some lane stores to it.  Inactive lanes are redirected to the sentinel
+word and rewrite its current value, so a scatter over a batch of blocks
+is exact for the blocks that do not store.  Cross-warp stores to the
+same address in one step have an implementation-defined winner (XLA
+scatter with duplicate indices) — the CUDA-race semantics the paper's
+race-free programs never observe; CUDA gives no stronger guarantee
+either.
 """
 from __future__ import annotations
 
@@ -25,12 +31,18 @@ from .state import MachineConfig, SMState
 from .fetch_decode import Decoded
 from .read import Operands
 
+class Store(NamedTuple):
+    """One memory port's stores of a trip, per (warp, lane)."""
+    mask: jnp.ndarray   # (W, 32) bool — lanes that store
+    addr: jnp.ndarray   # (W, 32) int32 — clipped word address
+    val: jnp.ndarray    # (W, 32) int32
+
+
 class Written(NamedTuple):
     regs: jnp.ndarray
     pred: jnp.ndarray
-    smem: jnp.ndarray
-    gmem: jnp.ndarray
-    gw: jnp.ndarray
+    glob: Store
+    shared: Store
 
 
 def _write_col(table: jnp.ndarray, idx: jnp.ndarray, wr: jnp.ndarray,
@@ -46,8 +58,6 @@ def _write_col(table: jnp.ndarray, idx: jnp.ndarray, wr: jnp.ndarray,
 def write_back(cfg: MachineConfig, st: SMState, dec: Decoded,
                ops: Operands, result: jnp.ndarray,
                nib_new: jnp.ndarray) -> Written:
-    G = st.gmem.shape[0] - 1
-
     # ---- register writeback (opcode-class bitmask test, per warp) ------
     has_dst = ((jnp.int32(isa.WRITES_REG_MASK) >> dec.op) & 1) != 0
     regs = _write_col(st.regs, dec.dst, ops.exec_mask & has_dst[:, None],
@@ -58,16 +68,28 @@ def write_back(cfg: MachineConfig, st: SMState, dec: Decoded,
     pred = _write_col(st.pred, dec.pdst, ops.exec_mask & is_setp[:, None],
                       nib_new)
 
-    # global / shared stores (inactive lanes write the sentinel word)
+    # global / shared store requests, applied by the store phase
     st_g = ops.exec_mask & (dec.op == isa.STG)[:, None]
-    gidx = jnp.where(st_g, ops.gaddr, G).ravel()
-    gval = jnp.where(st_g, ops.s2, st.gmem[G]).ravel()
-    gmem = st.gmem.at[gidx].set(gval)
-    gwrt = st.gw.at[gidx].set(st.gw[gidx] | st_g.ravel())
-
     st_s = ops.exec_mask & (dec.op == isa.STS)[:, None]
-    sidx = jnp.where(st_s, ops.saddr, cfg.smem_words).ravel()
-    sval = jnp.where(st_s, ops.s2, st.smem[cfg.smem_words]).ravel()
-    smem = st.smem.at[sidx].set(sval)
+    return Written(regs=regs, pred=pred,
+                   glob=Store(st_g, ops.gaddr, ops.s2),
+                   shared=Store(st_s, ops.saddr, ops.s2))
 
-    return Written(regs=regs, pred=pred, smem=smem, gmem=gmem, gw=gwrt)
+
+def store_global(gmem: jnp.ndarray, gw: jnp.ndarray, req: Store):
+    """``(gmem, gw)`` after one trip's global stores: a scatter into
+    memory and one into the written mask, each lane that does not store
+    rewriting the sentinel word with its own value."""
+    G = gmem.shape[0] - 1
+    gidx = jnp.where(req.mask, req.addr, G).ravel()
+    gval = jnp.where(req.mask, req.val, gmem[G]).ravel()
+    return (gmem.at[gidx].set(gval),
+            gw.at[gidx].set(gw[gidx] | req.mask.ravel()))
+
+
+def store_shared(smem: jnp.ndarray, req: Store) -> jnp.ndarray:
+    """``smem`` after one trip's shared stores (sentinel as above)."""
+    S = smem.shape[0] - 1
+    sidx = jnp.where(req.mask, req.addr, S).ravel()
+    sval = jnp.where(req.mask, req.val, smem[S]).ravel()
+    return smem.at[sidx].set(sval)

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..core import isa
 from ..core.pipeline import Counters, MachineConfig, run_block_body, \
-    split_trips
+    split_trips, store_trips
 from ..obs import METRICS, TRACER, MetricsRegistry, Tracer, jit_call
 from . import registry as reg
 from .registry import Module, ModuleRegistry
@@ -299,10 +299,12 @@ class DeviceGrid:
     rows are real blocks, and its ``device-execute`` span.  Padding is
     stripped on the host too, so a group costs the device nothing
     beyond its run; with ``tracer`` enabled each group's span gets
-    ``trips``, ``useful_steps`` and ``width`` (:meth:`loop_steps`) and
-    its real blocks' ``winstr``, ``lanes`` and ``stack_ops``
+    ``trips``, ``useful_steps`` and ``width`` (:meth:`loop_steps`), its
+    real blocks' ``winstr``, ``lanes`` and ``stack_ops``
     (:func:`_group_counts`), whose totals also go to the ``simt.*``
-    counters of ``metrics``.
+    counters of ``metrics``, and its loop's ``gstore_trips`` and
+    ``sstore_trips`` (:func:`_store_counts`), whose totals go to the
+    ``smstep.*`` counters.
     """
 
     def __init__(self, *, gmems, ctrs: Counters, split, sm_cyc, n_sm: int,
@@ -359,6 +361,7 @@ class DeviceGrid:
             with self._tracer.span("counter-sync", n_sm=self.n_sm,
                                    n_blocks=int(sum(self._blocks))):
                 c, sm_cyc = jax.device_get((self._ctrs, self._sm_cyc))
+            stores = _store_counts(c, self._starts)
             c, trips = self._split(c)
             # strip the padding: counter row == global block position
             per_group = [start + keep for start, (keep, _, _)
@@ -370,10 +373,13 @@ class DeviceGrid:
             for k in ("winstr", "lanes", "stack_ops"):
                 self._metrics.counter("simt." + k).inc(
                     sum(g[k] for g in counts))
+            for k in ("gstore_trips", "sstore_trips"):
+                self._metrics.counter("smstep." + k).inc(
+                    sum(g[k] for g in stores))
             if self._tracer.enabled:
-                for (_, _, sp), steps, cnt in zip(
-                        self._groups, self.loop_steps(), counts):
-                    sp.set(**steps, **cnt)
+                for (_, _, sp), steps, cnt, sto in zip(
+                        self._groups, self.loop_steps(), counts, stores):
+                    sp.set(**steps, **cnt, **sto)
         return self._host[:2]
 
     def loop_steps(self) -> List[dict]:
@@ -457,6 +463,21 @@ class DeviceGrid:
                 overflow=bool(overflow[sl].any())))
         self._results[host_gmem] = out
         return out
+
+
+def _store_counts(c: Counters, starts: np.ndarray) -> List[dict]:
+    """Per dispatch group, from the loop counters of all its rows:
+    ``gstore_trips`` / ``sstore_trips``, the trips on which its machine
+    loop ran the global / shared store scatter (the most of any row:
+    every row of a group carries its loop's count, and on the sharded
+    path each device's loop its own).  0 for counters without the
+    slots."""
+    n = np.zeros((starts[-1], 2), np.int64)
+    got = np.asarray(store_trips(c), np.int64)
+    n[:, :got.shape[-1]] = got
+    return [{"gstore_trips": int(g[:, 0].max()),
+             "sstore_trips": int(g[:, 1].max())}
+            for g in np.split(n, starts[1:-1])]
 
 
 def _group_counts(c: Counters, per_group: List[np.ndarray]) -> List[dict]:

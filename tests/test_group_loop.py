@@ -4,7 +4,9 @@
   unbatched machine loop leaves it — memory, written mask, every
   counter and the trip slot — under both issue disciplines, when the
   blocks stop at very different trips and when the runaway guard stops
-  one of them beside a block that runs to EXIT.
+  one of them beside a block that runs to EXIT.  The store-phase trips
+  past the trip slot are the group's: the same on every block, at
+  least any block's own and at most their sum.
 * What that rests on: stepping a block whose warps are all FINISHED,
   with no thread alive or active, leaves its memories bit-identical,
   sentinel words included.
@@ -20,7 +22,7 @@ from repro.compiler.kernels import spmv_csr
 from repro.core import isa
 from repro.core.pipeline import (EXECUTE_BACKENDS, FINISHED, TRIP_SLOT,
                                  MachineConfig, init_state, run_block_body,
-                                 split_trips, step_fn)
+                                 split_trips, step_fn, store_trips)
 
 N = 256                        # two blocks of 128 rows, 4 warps each
 N_WARPS = spmv_csr.BD // isa.WARP_SIZE
@@ -94,9 +96,15 @@ def _assert_group_matches_blocks(cfg, args):
         np.testing.assert_array_equal(mem[b], one_mem)
         np.testing.assert_array_equal(wrt[b], one_wrt)
         for f in one_ctr._fields:      # op_issues holds the trip slot
-            np.testing.assert_array_equal(getattr(ctr, f)[b],
-                                          getattr(one_ctr, f), err_msg=f)
+            got, want = getattr(ctr, f)[b], getattr(one_ctr, f)
+            if f == "op_issues":        # and the store-phase trips
+                got, want = got[:TRIP_SLOT + 1], want[:TRIP_SLOT + 1]
+            np.testing.assert_array_equal(got, want, err_msg=f)
         singles.append((one_wrt, one_ctr))
+    group = np.asarray(store_trips(ctr))
+    own = np.stack([np.asarray(store_trips(c)) for _, c in singles])
+    assert (group == group[0]).all()
+    assert (own.max(0) <= group[0]).all() and (group[0] <= own.sum(0)).all()
     return zip(*singles)
 
 

@@ -106,12 +106,76 @@ def test_run_positions_register_files_not_scattered(one_chip):
 SPMV_G_WORDS, SPMV_CODE_ROWS, SPMV_N_WARPS = 2097152, 64, 4
 
 
+def _guarded_scatters(text):
+    """For each branch of a ``conditional`` and each body of a
+    ``while`` in an HLO module's text, the shapes of the scatters it
+    reaches (through fusions and nested computations)."""
+    calls, scatters, guards, name = {}, {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            calls[name], scatters[name] = set(), set()
+            continue
+        if name is None:
+            continue
+        callees = re.findall(r"(?:calls|to_apply|condition|"
+                             r"true_computation|false_computation)"
+                             r"=%([\w.\-]+)", line)
+        body = re.search(r"body=%([\w.\-]+)", line)
+        branches = re.search(r"branch_computations=\{([^}]*)\}", line)
+        here = ([body.group(1)] if body else []) + (
+            re.findall(r"%([\w.\-]+)", branches.group(1)) if branches
+            else [])
+        guards += here
+        calls[name].update(callees + here)
+        hit = re.search(r"= (\w+\[[\d,]*\])\S* scatter\(", line)
+        if hit:
+            scatters[name].add(hit.group(1))
+
+    def reach(root):
+        seen, todo, shapes = set(), [root], set()
+        while todo:
+            c = todo.pop()
+            if c not in seen:
+                seen.add(c)
+                shapes |= scatters.get(c, set())
+                todo += calls.get(c, ())
+        return shapes
+    return [reach(g) for g in guards]
+
+
+def _assert_stores_guarded(text, g_words):
+    """Each memory port's scatters run under a guard of their own: a
+    branch or loop body that reaches the port's scatters — the global
+    row with the written mask, or shared memory — and no other scatter,
+    so the step runs them only when the guard holds."""
+    guarded = _guarded_scatters(text)
+    smem = MachineConfig().smem_words + 1
+    for port in ({f"s32[{N_SM},{g_words + 1}]",
+                  f"pred[{N_SM},{g_words + 1}]"},
+                 {f"s32[{N_SM},{smem}]"}):
+        assert port in guarded, (port, [g for g in guarded if g])
+
+
+def _assert_rows_not_copied(text, g_words):
+    """No copy — synchronous or asynchronous, as between on-chip memory
+    and HBM around a conditional — and no select of the group's memory
+    row, written mask or shared memory."""
+    ops = set(re.findall(
+        r"= \(?(\w+\[[\d,]*\])[^=]*? (copy|copy-start|select)\(", text))
+    for row in (f"s32[{N_SM},{g_words + 1}]", f"pred[{N_SM},{g_words + 1}]",
+                f"s32[{N_SM},{MachineConfig().smem_words + 1}]"):
+        assert not {op for r, op in ops if r == row}, (row, ops)
+
+
 def test_run_positions_memory_row_not_copied(one_chip):
     """A dispatch group's blocks run in one machine loop that takes the
     memories from the step unselected: at the largest memory bucket the
     compiled program holds no copy and no select of the group's memory
     row or written mask, and the global and written-mask stores stay
-    scatters of that shape (in place)."""
+    scatters of that shape (in place), each port's under a guard of its
+    own."""
     args = list(_positions_args(one_chip, one_chip, N_SM))
     args[0] = _shape((1, SPMV_CODE_ROWS, isa.NUM_FIELDS), one_chip)
     args[8] = _shape((1, SPMV_G_WORDS), one_chip)
@@ -123,6 +187,20 @@ def test_run_positions_memory_row_not_copied(one_chip):
                 f"pred[{N_SM},{SPMV_G_WORDS + 1}]"):
         assert (row, "scatter") in ops, row
         assert (row, "copy") not in ops and (row, "select") not in ops, row
+    _assert_rows_not_copied(text, SPMV_G_WORDS)
+    _assert_stores_guarded(text, SPMV_G_WORDS)
+
+
+def test_run_positions_stores_guarded(one_chip):
+    """The same at the paper suite's largest footprint, 8 warps, where
+    every memory row fits in on-chip memory: each port's scatters run
+    under a guard of their own, and no memory row is copied or
+    selected."""
+    text = ex._run_positions.lower(
+        MachineConfig(), N_WARPS,
+        *_positions_args(one_chip, one_chip, N_SM)).compile().as_text()
+    _assert_rows_not_copied(text, G_WORDS)
+    _assert_stores_guarded(text, G_WORDS)
 
 
 def test_sharded_run_positions_compiles(topo):
